@@ -10,14 +10,14 @@ stream. Both are asserted IN-ARM: a run that fails either raises.
 
 The device round trip is simulated (testing/rtt_shim.SimulatedRTT, the
 fused-dispatch precedent): each window pays a sleeping RTT on the thread
-that would pay it over a real tunnel, and sleeps overlap across the
+that would pay it on a real device, and sleeps overlap across the
 fleet's per-cluster worker threads exactly as the per-device RPCs would.
 On this 2-core CPU rig the XLA solve itself is ~ms and partially
 serializes on the shared CPU backend; the RTT is what scales, which is
-honest to the production shape where the tunnel dominates.
+honest to a deployment where the device round trip dominates.
 
 The STACKED section (ISSUE 20 bar: >=1.5x at F=4 / 40 ms) runs both its
-arms under `tunnel_serialized=True` — one shared device link, where F
+arms under `link_serialized=True` — one shared device link, where F
 concurrent per-cluster round trips queue instead of overlapping. That is
 the regime the fused fleet dispatch exists for: the unstacked fleet pays
 F serialized RTTs per round of windows, the stacked fleet gathers them
@@ -277,7 +277,7 @@ def main():
 
 def run_stacked_section(args, cfg):
     """ISSUE 20 A/B: stacked vs unstacked fleet over ONE shared device
-    link (tunnel_serialized RTT), interleaved arms on the same offered
+    link (link_serialized RTT), interleaved arms on the same offered
     load. See the module docstring for the protocol."""
     import statistics
 
@@ -340,7 +340,7 @@ def run_stacked_section(args, cfg):
         # Untimed warm round: compiles (incl. the stacked kernel's
         # [M, B, N] shapes when stacking is on) happen here.
         drive("warm", 1)
-        with SimulatedRTT(args.rtt_ms, tunnel_serialized=True):
+        with SimulatedRTT(args.rtt_ms, link_serialized=True):
             wall = drive(f"rep{rep}", args.apps_per_cluster)
         if errors:
             raise errors[0]
@@ -400,7 +400,7 @@ def run_stacked_section(args, cfg):
             "stack_arms": st_line.get("stack_arms", 0),
             "detail": {
                 "rtt_ms": args.rtt_ms,
-                "tunnel_serialized": True,
+                "link_serialized": True,
                 "stack_window_ms": (
                     0.0 if mode == "unstacked" else args.stack_window_ms
                 ),

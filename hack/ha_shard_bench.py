@@ -4,8 +4,7 @@ Measures 2-active-replica instance-group sharding (ha/replica.py
 ShardedServingGroup) against a single unsharded replica on the SAME
 workload, twice: a pure-CPU arm (informational — a single XLA CPU solve
 already saturates every host core, so two concurrent solves cannot scale
-there) and a simulated-RTT arm (testing/rtt_shim.py, the tunneled-TPU
-regime the paper deploys on, where the control serializes one device
+there) and a simulated-RTT arm (testing/rtt_shim.py, where the control serializes one device
 round trip per window and the shards overlap theirs — the arm that
 carries the >= 1.5x bar). Byte-identical per-group placements are
 ASSERTED in both arms. Then runs the leader-kill chaos soak
@@ -203,16 +202,11 @@ def sharded_arm(nodes_per_group: int, rtt_ms):
 def main() -> None:
     from spark_scheduler_tpu.server.config import InstallConfig
 
-    InstallConfig.enable_jax_compile_cache(
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache",
-        )
-    )
+    InstallConfig.enable_jax_compile_cache()
     # Pure-CPU arm: informational on shared-core boxes.
     pure = sharded_arm(512, None)
     print(json.dumps({"arm": "pure_cpu", **pure}), flush=True)
-    # Tunneled-TPU regime: 50 ms simulated device RTT per window — the
+    # 50 ms simulated device RTT per window — the
     # control serializes round trips, the shards overlap theirs. This arm
     # carries the >= 1.5x bar.
     rtt = sharded_arm(256, 50.0)

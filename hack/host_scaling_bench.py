@@ -23,8 +23,8 @@ measuring the numbers the tier is judged on:
                        reused-plan 16-wide host cost must track window
                        size, not cluster size).
 
-Everything runs in process against the local jax backend: no HTTP hop, no
-tunnel — this is the HOST scaling story. Candidate names ride an
+Everything runs in process against the local jax backend: no HTTP hop —
+this is the HOST scaling story. Candidate names ride an
 identity-keyed ticket (the in-process analog of the native ingest lane's
 digest ticket) so the 1M-name candidate list is not re-hashed per request.
 """

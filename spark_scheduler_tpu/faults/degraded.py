@@ -2,7 +2,7 @@
 serve.
 
 The reference has no analogue (its "device" is a Go for-loop); here a
-tunneled-TPU deployment can lose every pool slot at once (tunnel cut,
+deployment can lose every pool slot at once (host-device link lost,
 driver OOM) and a millions-of-users front-end needs a defined answer:
 
   greedy  keep serving: the extender solves on the HOST via the promoted

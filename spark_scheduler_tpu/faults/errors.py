@@ -1,14 +1,16 @@
 """Exception taxonomy of the fault-tolerance subsystem.
 
 The split that matters operationally is SLOT-FATAL vs PROGRAMMING ERROR:
-a device slot whose solve died of a tunnel drop / XlaRuntimeError should
-be quarantined and its window re-dispatched on a survivor, while a
-TypeError in the packing code must propagate loudly — retrying it on
+a device slot whose solve died of a runtime error or a lost transport
+should be quarantined and its window re-dispatched on a survivor, while
+a TypeError in the packing code must propagate loudly — retrying it on
 another slot would fail identically and hide the bug.
 `classify_slot_failure` draws that line in one place.
 """
 
 from __future__ import annotations
+
+from jax.errors import JaxRuntimeError
 
 
 class InjectedFault(RuntimeError):
@@ -22,7 +24,7 @@ class InjectedFault(RuntimeError):
 
 class DeviceFaultError(InjectedFault):
     """Injected DEVICE-surface fault (h2d / dispatch / d2h): classified
-    slot-fatal, exactly like a real tunnel drop or XlaRuntimeError."""
+    slot-fatal, exactly like a real device runtime error."""
 
 
 class AllSlotsQuarantinedError(RuntimeError):
@@ -57,24 +59,22 @@ class BreakerOpenError(RuntimeError):
     downstream is failing; probing is rationed to the half-open window)."""
 
 
-# Exception type names that mean "the DEVICE (or its transport) died", as
-# opposed to "the program is wrong". Matched by name so the classifier
-# needs no jaxlib import (the concrete class moved modules across jax
-# releases).
-_SLOT_FATAL_TYPE_NAMES = frozenset(
-    {"XlaRuntimeError", "ChannelError", "RpcError"}
-)
-
-
 def classify_slot_failure(exc: BaseException) -> bool:
     """True when `exc` indicates the device slot (hardware, runtime, or
-    tunnel) failed and the work should be retried on a surviving slot;
-    False for programming errors that must propagate."""
-    if isinstance(exc, DeviceFaultError):
-        return True
-    if isinstance(exc, (ConnectionError, TimeoutError, OSError)):
-        return True
-    for klass in type(exc).__mro__:
-        if klass.__name__ in _SLOT_FATAL_TYPE_NAMES:
-            return True
-    return False
+    transport) failed and the work should be retried on a surviving slot;
+    False for programming errors that must propagate.
+
+    `jax.errors.JaxRuntimeError` is what the runtime raises for a failed
+    execution or transfer. A failed COMPILE raises the same class, so the
+    solver compiles its programs before the dispatch that this classifier
+    guards (`PlacementSolver._pallas_window_program`)."""
+    return isinstance(
+        exc,
+        (
+            DeviceFaultError,
+            JaxRuntimeError,
+            ConnectionError,
+            TimeoutError,
+            OSError,
+        ),
+    )

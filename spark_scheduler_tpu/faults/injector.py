@@ -27,7 +27,7 @@ Named surfaces (dot-paths; specs match with fnmatch patterns):
 Actions: "error" (raise; DeviceFaultError on device.* so the solver's
 slot classifier quarantines), "latency" (sleep latency_ms), "partition"
 (a contiguous window of matching events all error — a dead apiserver /
-dropped tunnel, not a blip).
+lost device, not a blip).
 """
 
 from __future__ import annotations

@@ -28,6 +28,7 @@ lanes instead of taking the server down.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -39,6 +40,7 @@ _REPO_ROOT = os.path.dirname(
 )
 _SRC = os.path.join(_REPO_ROOT, "native", "runtime.cpp")
 _SO = os.path.join(_REPO_ROOT, "native", "build", "libsched_runtime.so")
+_SO_DIGEST = _SO + ".sha256"
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -66,8 +68,18 @@ def _note_failure(message: str) -> None:
         pass
 
 
-def _build() -> bool:
+def _src_digest() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _build(digest: str) -> bool:
+    """Compile runtime.cpp into _SO and record the source digest beside
+    it. The library is written under a per-process name and renamed into
+    place, so a concurrent loader never maps a half-written file."""
     os.makedirs(os.path.dirname(_SO), exist_ok=True)
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp_digest = f"{_SO_DIGEST}.{os.getpid()}.tmp"
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O2",
@@ -75,11 +87,15 @@ def _build() -> bool:
         "-fPIC",
         "-shared",
         "-o",
-        _SO,
+        tmp,
         _SRC,
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
+        with open(tmp_digest, "w") as f:
+            f.write(digest)
+        os.replace(tmp_digest, _SO_DIGEST)
         return True
     except FileNotFoundError:
         _note_failure(f"compiler not found: {cmd[0]}")
@@ -232,17 +248,18 @@ def _load():
     with _lib_lock:
         if _lib is not None or _load_failed:
             return _lib
-        try:
-            # Source may be absent in installed artifacts with a cached .so;
-            # only rebuild when the source exists and is newer.
-            stale = not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-        except OSError:
-            stale = not os.path.exists(_SO)
-        if stale:
-            if not _build():
+        # Rebuild unless the recorded digest of the source the .so was
+        # built from matches runtime.cpp as it is now: a library copied in
+        # from another tree is never loaded. Installed artifacts without
+        # the source load their shipped .so.
+        if os.path.exists(_SRC):
+            digest = _src_digest()
+            try:
+                with open(_SO_DIGEST) as f:
+                    stale = f.read().strip() != digest
+            except OSError:
+                stale = True
+            if (stale or not os.path.exists(_SO)) and not _build(digest):
                 _load_failed = True
                 return None
         try:

@@ -146,22 +146,19 @@ def _install_listener() -> None:
     with _totals_lock:
         if _listener_state["installed"]:
             return
-        try:
-            from jax import monitoring
+        from jax import monitoring
 
-            def _on_duration(event: str, duration: float, **kw) -> None:
-                if _COMPILE_EVENT in event:
-                    # jax calls listeners from the compiling thread; the
-                    # GIL makes these two updates effectively atomic
-                    # enough for telemetry, but take the lock anyway —
-                    # compiles are rare and the lock is uncontended.
-                    with _totals_lock:
-                        _totals["count"] += 1
-                        _totals["seconds"] += float(duration)
+        def _on_duration(event: str, duration: float, **kw) -> None:
+            if _COMPILE_EVENT in event:
+                # jax calls listeners from the compiling thread; the GIL
+                # makes these two updates effectively atomic enough for
+                # telemetry, but take the lock anyway — compiles are rare
+                # and the lock is uncontended.
+                with _totals_lock:
+                    _totals["count"] += 1
+                    _totals["seconds"] += float(duration)
 
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            pass  # jax without monitoring: compile stats stay zero
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_state["installed"] = True
 
 

@@ -63,6 +63,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from spark_scheduler_tpu.models.cluster import ClusterTensors, INT32_INF
 from spark_scheduler_tpu.ops.batched import (
@@ -92,6 +94,19 @@ _SUBLANES = 8  # VPU sublanes
 _SUBLANE_FOLD_MIN_NODES = 4096
 
 
+# Both Mosaic kernels keep the whole node axis resident in VMEM, so their
+# size is bounded by it. Compiled for a described v5e (PR 21, padded node
+# buckets): at 131,072 nodes every served window bucket compiled, up to
+# 256 segments x 256 rows; at 262,144 the window kernel compiled with 16
+# and 64 rows but not 256, and 524,288 and 1,048,576 were refused for
+# both kernels ("Ran out of memory in memory space vmem"). The queue
+# kernel prefetches its per-app requests into SMEM: 1,024 apps overflowed
+# it ("Used 1.01M of 1.00M smem"), 512 fit. Larger shapes route to the XLA
+# scan: a rule on the shape, never a caught error.
+PALLAS_MAX_NODES = 131_072
+PALLAS_MAX_APPS = 512
+
+
 def _layout_rows(n: int) -> int:
     return _SUBLANES if n >= _SUBLANE_FOLD_MIN_NODES else 1
 
@@ -105,14 +120,16 @@ def pallas_eligible(apps: "AppBatch", fill: str) -> bool:
     plain queue mode (no per-app masks, no segmented windows) with any of
     the six strategies — the three plain fills, and since r4 the
     single-AZ wrappers (per-zone fill + efficiency-scored zone pick
-    in-kernel). Shared by every routing site so eligibility cannot drift
-    when the kernel learns new shapes. (Segmented serving windows have
-    their own Mosaic path, ops/pallas_window.)"""
+    in-kernel), up to PALLAS_MAX_APPS apps. Shared by every routing site
+    so eligibility cannot drift when the kernel learns new shapes.
+    (Segmented serving windows have their own Mosaic path,
+    ops/pallas_window.)"""
     return (
         (fill in PALLAS_FILLS or fill in PALLAS_SINGLE_AZ)
         and apps.commit is None
         and apps.driver_cand is None
         and apps.domain is None
+        and apps.driver_req.shape[-2] <= PALLAS_MAX_APPS
     )
 
 
@@ -559,17 +576,6 @@ def _make_kernel(
     return kernel
 
 
-# Deferred imports so the module imports cleanly where jax.experimental
-# pallas is unavailable (the routing layer falls back to the XLA scan).
-try:  # pragma: no cover - import guard
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
-
-
 @partial(
     jax.jit, static_argnames=("fill", "emax", "num_zones", "interpret")
 )
@@ -596,7 +602,8 @@ def fifo_pack_pallas(
             f"pallas path supports queue mode with "
             f"{PALLAS_FILLS + tuple(PALLAS_SINGLE_AZ)}, got "
             f"fill={fill!r} masked={apps.driver_cand is not None or apps.domain is not None} "
-            f"segmented={apps.commit is not None}"
+            f"segmented={apps.commit is not None} "
+            f"apps={apps.driver_req.shape[0]} (max {PALLAS_MAX_APPS})"
         )
 
     n = cluster.available.shape[0]
@@ -708,27 +715,31 @@ _PALLAS_AVAILABLE: bool | None = None
 
 
 def pallas_available() -> bool:
-    """True when the default backend can compile Mosaic kernels (probed
-    once with a trivial kernel and cached)."""
+    """Whether the routing layer may send work to the Mosaic kernels.
+
+    False on any backend but a TPU (the tests' CPU backend keeps the XLA
+    scan). On a TPU a trivial kernel is compiled and run once and the
+    answer cached; if that probe fails, its error propagates: a broken
+    Mosaic toolchain on the chip must stop the program, never turn into
+    a quiet switch to the slower scan."""
     global _PALLAS_AVAILABLE
     if _PALLAS_AVAILABLE is None:
-        if not _PALLAS_IMPORTED:
+        if jax.default_backend() != "tpu":
             _PALLAS_AVAILABLE = False
             return False
-        try:
 
-            def _probe(x_ref, o_ref):
-                o_ref[:] = x_ref[:] + 1
+        def _probe(x_ref, o_ref):
+            o_ref[:] = x_ref[:] + 1
 
-            out = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8, _LANES), jnp.int32),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-                out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            )(jnp.zeros((8, _LANES), jnp.int32))
-            _PALLAS_AVAILABLE = bool(np.asarray(out)[0, 0] == 1)
-        except Exception:
-            _PALLAS_AVAILABLE = False
+        out = pl.pallas_call(
+            _probe,
+            out_shape=jax.ShapeDtypeStruct((8, _LANES), jnp.int32),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        )(jnp.zeros((8, _LANES), jnp.int32))
+        if not np.all(np.asarray(out) == 1):
+            raise RuntimeError("Mosaic probe kernel returned a wrong value")
+        _PALLAS_AVAILABLE = True
     return _PALLAS_AVAILABLE
 
 
@@ -742,11 +753,17 @@ def fifo_pack_auto(
     prefer_pallas: bool = True,
 ) -> BatchedPacking:
     """Route a queue solve to the Pallas kernel when the backend supports
-    Mosaic and the request is queue-mode with a plain fill; otherwise the
-    XLA scan. Decisions are identical either way (golden-parity tested)."""
+    Mosaic, the request is queue-mode and the cluster fits the kernel
+    (PALLAS_MAX_NODES); otherwise the XLA scan. Decisions are identical
+    either way (golden-parity tested)."""
     from spark_scheduler_tpu.ops.batched import batched_fifo_pack
 
-    if prefer_pallas and pallas_eligible(apps, fill) and pallas_available():
+    if (
+        prefer_pallas
+        and pallas_eligible(apps, fill)
+        and cluster.available.shape[0] <= PALLAS_MAX_NODES
+        and pallas_available()
+    ):
         return fifo_pack_pallas(
             cluster, apps, fill=fill, emax=emax, num_zones=num_zones
         )

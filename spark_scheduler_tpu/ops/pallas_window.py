@@ -41,12 +41,15 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from spark_scheduler_tpu.models.cluster import ClusterTensors, INT32_INF
 from spark_scheduler_tpu.ops.packing import _rank_of_position
 from spark_scheduler_tpu.ops.sorting import priority_order, zone_ranks
 from spark_scheduler_tpu.ops.pallas_fifo import (
     PALLAS_FILLS,
+    PALLAS_MAX_NODES,
     PALLAS_SINGLE_AZ,
     _LANES,
     _layout_rows,
@@ -54,14 +57,6 @@ from spark_scheduler_tpu.ops.pallas_fifo import (
     make_gang_solver,
     pallas_available,
 )
-
-try:  # pragma: no cover - import guard (mirrors pallas_fifo)
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
 
 
 class SegmentedWindow(NamedTuple):
@@ -447,11 +442,14 @@ def make_segmented_window(
     return win
 
 
-def window_pallas_eligible(fill: str) -> bool:
-    """Whether the segmented serving-window Pallas path can serve this
-    strategy on this backend — all six (the plain fills, and since r5 the
-    single-AZ wrappers: per-zone fill + efficiency-scored zone pick through
-    the shared make_gang_solver)."""
+def window_pallas_eligible(fill: str, n_nodes: int) -> bool:
+    """Whether the segmented serving-window Pallas path serves this
+    strategy at this (padded) node count on this backend — all six
+    strategies (the plain fills, and the single-AZ wrappers: per-zone
+    fill + efficiency-scored zone pick through the shared
+    make_gang_solver), up to the VMEM bound PALLAS_MAX_NODES."""
     return (
-        fill in PALLAS_FILLS or fill in PALLAS_SINGLE_AZ
-    ) and pallas_available()
+        (fill in PALLAS_FILLS or fill in PALLAS_SINGLE_AZ)
+        and n_nodes <= PALLAS_MAX_NODES
+        and pallas_available()
+    )

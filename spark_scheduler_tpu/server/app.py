@@ -111,10 +111,7 @@ def build_scheduler_app(
 
     config = config or InstallConfig()
     clock = clock or _time.time
-    if config.jax_compilation_cache_dir:
-        InstallConfig.enable_jax_compile_cache(
-            config.jax_compilation_cache_dir
-        )
+    InstallConfig.enable_jax_compile_cache(config.jax_compilation_cache_dir)
 
     # The scheduler owns its reservation CRD: create-or-upgrade + verify
     # Established before anything consumes it (cmd/server.go:103-109); the

@@ -175,7 +175,7 @@ class PredicateBatcher:
         self._queue: list[list] = []  # [args, event, result, exception, trace]
         # Entries the dispatcher has claimed whose events may not be set
         # yet — what stop() fails when the dispatcher thread is stalled in
-        # a blocking fetch against a dead tunnel (join times out but
+        # a blocking fetch against a dead device (join times out but
         # in-flight HTTP handlers must not hang until request timeout).
         # Entries are REMOVED on completion (_finish_entries), so a
         # timed-out-then-completed request never leaves a slot behind.
@@ -268,7 +268,7 @@ class PredicateBatcher:
         # Fail every claimed/queued entry whose event is still unset so
         # in-flight handlers return instead of hanging until their own
         # request timeout — covers a dispatcher STALLED in a decision pull
-        # against a dead tunnel (join timed out) and one that DIED with a
+        # against a dead device (join timed out) and one that DIED with a
         # batch's events unset. No-op on a clean exit (everything is set);
         # a late set() by a stalled thread is harmless (set is idempotent
         # for both entry kinds).

@@ -593,7 +593,7 @@ class ChaosMatrixSoak:
                           mode="error", at=[3], limit=1),
             ],
             "device": [
-                # The 3rd h2d dies (tunnel drop mid-soak): that window is
+                # The 3rd h2d dies (device lost mid-soak): that window is
                 # served by the host greedy fallback; the next dispatch
                 # recovers the device path.
                 FaultSpec(surface="device.h2d", mode="error",

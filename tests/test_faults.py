@@ -481,14 +481,15 @@ def test_degraded_controller_rejects_unknown_policy():
 
 
 def test_classify_slot_failure_taxonomy():
-    class XlaRuntimeError(RuntimeError):
-        pass
+    import jax
 
     assert classify_slot_failure(DeviceFaultError("device.d2h"))
-    assert classify_slot_failure(ConnectionError("tunnel drop"))
+    assert classify_slot_failure(ConnectionError("connection reset"))
     assert classify_slot_failure(TimeoutError("rpc deadline"))
     assert classify_slot_failure(OSError("broken pipe"))
-    assert classify_slot_failure(XlaRuntimeError("device failed"))
+    assert classify_slot_failure(
+        jax.errors.JaxRuntimeError("INTERNAL: device failed")
+    )
     assert not classify_slot_failure(TypeError("programming error"))
     assert not classify_slot_failure(ValueError("bad shape"))
     assert not classify_slot_failure(InjectedFault("backend.pods.create"))
@@ -728,3 +729,78 @@ def test_injector_on_fire_publishes_fault_telemetry():
         action="error",
     )
     assert counter.value == 2  # limit capped the third fire
+
+
+# ------------------------------------------- compile vs runtime device error
+
+
+def _tiny_window_solver():
+    from spark_scheduler_tpu.core.solver import PlacementSolver, WindowRequest
+    from spark_scheduler_tpu.models.kube import Node
+    from spark_scheduler_tpu.models.resources import Resources
+
+    solver = PlacementSolver(use_native=False)
+    solver.degraded = DegradedModeController(policy="greedy")
+    nodes = [
+        Node(name=f"n{i}", allocatable=Resources.from_quantities("8", "8Gi"))
+        for i in range(12)
+    ]
+    tensors = solver.build_tensors(nodes, {}, {})
+    one = Resources.from_quantities("1", "1Gi")
+    requests = [
+        WindowRequest(
+            rows=[(one, one, 3, False)],
+            driver_candidate_names=[n.name for n in nodes],
+        )
+    ]
+    return solver, tensors, requests
+
+
+def test_window_compile_failure_propagates_past_degraded_fallback(
+    monkeypatch,
+):
+    """jax raises a refused Mosaic compile as the same JaxRuntimeError as a
+    failed device. The window program compiles before the dispatch's
+    slot-failure handler, so the compile error propagates and the greedy
+    degraded fallback never serves the window."""
+    import jax
+
+    from spark_scheduler_tpu.ops import pallas_window as pw
+
+    def refused_kernel(*args, **kwargs):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel"
+        )
+
+    monkeypatch.setattr(pw, "window_pallas_eligible", lambda fill, n: True)
+    monkeypatch.setattr(pw, "window_pack_pallas", refused_kernel)
+    solver, tensors, requests = _tiny_window_solver()
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        solver.pack_window("tightly-pack", tensors, requests)
+    assert "greedy-fallback" not in solver.window_path_counts
+    assert solver.degraded.engagements == 0
+
+
+def test_window_runtime_failure_serves_degraded(monkeypatch):
+    """The same error class raised by the compiled program as it runs is a
+    device failure: the window is served by the greedy fallback."""
+    import jax
+
+    from spark_scheduler_tpu.core.solver import PlacementSolver
+    from spark_scheduler_tpu.ops import pallas_window as pw
+
+    def failing_program(*args, **kwargs):
+        def run(*a):
+            raise jax.errors.JaxRuntimeError("INTERNAL: device halted")
+
+        return run
+
+    monkeypatch.setattr(pw, "window_pallas_eligible", lambda fill, n: True)
+    monkeypatch.setattr(
+        PlacementSolver, "_pallas_window_program", failing_program
+    )
+    solver, tensors, requests = _tiny_window_solver()
+    (decision,) = solver.pack_window("tightly-pack", tensors, requests)
+    assert decision.admitted
+    assert solver.window_path_counts.get("greedy-fallback") == 1
+    assert solver.degraded.engagements == 1

@@ -214,3 +214,28 @@ def test_native_queue_concurrent_producers_consumers():
     [c.join(timeout=5) for c in consumers]
     assert len(consumed) == n_per * n_prod  # distinct keys: nothing deduped
     assert len(set(consumed)) == n_per * n_prod
+
+
+def test_library_built_from_other_source_is_rebuilt(tmp_path, monkeypatch):
+    """A .so whose recorded source digest does not match runtime.cpp is
+    never loaded: it is rebuilt from the source as it is."""
+    import hashlib
+    import shutil
+
+    from spark_scheduler_tpu import native
+
+    src = tmp_path / "runtime.cpp"
+    shutil.copy(native._SRC, src)
+    so = tmp_path / "build" / "libsched_runtime.so"
+    so.parent.mkdir()
+    so.write_bytes(b"built elsewhere")
+    digest = tmp_path / "build" / "libsched_runtime.so.sha256"
+    digest.write_text("0" * 64)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_SO_DIGEST", str(digest))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_failed", False)
+    assert native._load() is not None
+    assert digest.read_text() == hashlib.sha256(src.read_bytes()).hexdigest()
+    assert so.read_bytes() != b"built elsewhere"

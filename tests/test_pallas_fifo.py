@@ -284,3 +284,51 @@ def test_auto_routing_falls_back_on_cpu():
     got = fifo_pack_auto(c, apps, fill="tightly-pack", emax=EMAX,
                          num_zones=NUM_ZONES)
     assert_same(got, want)
+
+
+def test_mosaic_probe_raises_on_tpu_backend(monkeypatch):
+    """On a backend reported as a TPU, a failing Mosaic probe raises its
+    error instead of quietly routing everything to the XLA scan. (Here
+    the probe really fails: the CPU backend cannot run a compiled Mosaic
+    kernel.)"""
+    import jax
+
+    from spark_scheduler_tpu.ops import pallas_fifo as pf
+
+    monkeypatch.setattr(pf, "_PALLAS_AVAILABLE", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(Exception):
+        pf.pallas_available()
+    assert pf._PALLAS_AVAILABLE is None  # never cached as "unavailable"
+
+
+def test_mosaic_probe_is_false_on_cpu(monkeypatch):
+    from spark_scheduler_tpu.ops import pallas_fifo as pf
+
+    monkeypatch.setattr(pf, "_PALLAS_AVAILABLE", None)
+    assert pf.pallas_available() is False
+
+
+def test_kernels_are_routed_by_shape(monkeypatch):
+    """Above PALLAS_MAX_NODES (the measured VMEM bound) or PALLAS_MAX_APPS
+    (the queue kernel's SMEM bound) the routing sends work past the Mosaic
+    kernels, decided from the shape alone."""
+    from spark_scheduler_tpu.ops import pallas_fifo as pf
+    from spark_scheduler_tpu.ops import pallas_window as pw
+
+    monkeypatch.setattr(pw, "pallas_available", lambda: True)
+    assert pw.window_pallas_eligible("tightly-pack", pf.PALLAS_MAX_NODES)
+    assert not pw.window_pallas_eligible(
+        "tightly-pack", 2 * pf.PALLAS_MAX_NODES
+    )
+
+    def queue(b):
+        return make_app_batch(
+            np.ones((b, 3)), np.ones((b, 3)), np.ones(b),
+            skippable=np.zeros(b, bool),
+        )
+
+    assert pf.pallas_eligible(queue(pf.PALLAS_MAX_APPS), "tightly-pack")
+    assert not pf.pallas_eligible(
+        queue(pf.PALLAS_MAX_APPS + 1), "tightly-pack"
+    )

@@ -271,7 +271,7 @@ def test_solver_window_route_parity(monkeypatch):
     s_x, t_x, names = mk_solver()
     ref = s_x.pack_window("tightly-pack", t_x, mk_requests(names))
 
-    monkeypatch.setattr(pw, "window_pallas_eligible", lambda fill: True)
+    monkeypatch.setattr(pw, "window_pallas_eligible", lambda fill, n: True)
     monkeypatch.setattr(
         pw, "window_pack_pallas", _p(pw.window_pack_pallas, interpret=True)
     )

@@ -106,7 +106,7 @@ def test_slot_kill_mid_burst_byte_identical_on_survivor():
 
     pooled = PlacementSolver(use_native=False, device_pool=2)
     assert pooled.pool_size == 2
-    # The 3rd partition solve dies (window 2's first part): tunnel drop
+    # The 3rd partition solve dies (window 2's first part): device lost
     # mid-burst, classified slot-fatal via DeviceFaultError.
     plan = FaultPlan(
         seed=0, name="slot-kill",
