@@ -616,36 +616,39 @@ class SparkSchedulerExtender:
         # commits state) + ONE FIFO pending-driver scan for the whole
         # fused claim. The shared phase costs are attributed to the
         # sub-windows in equal shares — amortization is the point.
-        featurize_start = self._clock()
-        snap = self.features.snapshot()
-        t_snap = self._clock()
-        snapshot_ms = (t_snap - featurize_start) * 1e3
-        tensors = self._solver.build_tensors_pipelined(
-            snap.nodes, snap.usage, snap.overhead,
-            topo_version=snap.nodes_version,
-            statics_version=snap.statics_epoch,
-            roster_rows=snap.roster_rows,
-            dirty_hint=snap.dirty_hint,
-            avail_epoch=snap.avail_epoch,
-            avail_journal=snap.avail_journal,
-        )
-        t_tensors = self._clock()
-        tensors_ms = (t_tensors - t_snap) * 1e3
-        pending_supplier = self._pending_driver_supplier()
-        share = max(1, len(live))
-        seen_apps: set[tuple[str, str]] = set(self._inflight_apps)
-        staged: list[tuple[WindowTicket, list[WindowRequest]]] = []
-        for t in live:
-            t.featurize_phases["featurize_snapshot_ms"] = snapshot_ms / share
-            t.featurize_phases["featurize_tensors_ms"] = tensors_ms / share
-            driver_ids = driver_ids_of[id(t)]
-            if not driver_ids:
-                continue
-            requests = self._stage_driver_window(
-                t, driver_ids, snap, seen_apps, pending_supplier
+        from spark_scheduler_tpu.tracing import tracer
+
+        with tracer().span("featurize"):
+            featurize_start = self._clock()
+            snap = self.features.snapshot()
+            t_snap = self._clock()
+            snapshot_ms = (t_snap - featurize_start) * 1e3
+            tensors = self._solver.build_tensors_pipelined(
+                snap.nodes, snap.usage, snap.overhead,
+                topo_version=snap.nodes_version,
+                statics_version=snap.statics_epoch,
+                roster_rows=snap.roster_rows,
+                dirty_hint=snap.dirty_hint,
+                avail_epoch=snap.avail_epoch,
+                avail_journal=snap.avail_journal,
             )
-            if requests:
-                staged.append((t, requests))
+            t_tensors = self._clock()
+            tensors_ms = (t_tensors - t_snap) * 1e3
+            pending_supplier = self._pending_driver_supplier()
+            share = max(1, len(live))
+            seen_apps: set[tuple[str, str]] = set(self._inflight_apps)
+            staged: list[tuple[WindowTicket, list[WindowRequest]]] = []
+            for t in live:
+                t.featurize_phases["featurize_snapshot_ms"] = snapshot_ms / share
+                t.featurize_phases["featurize_tensors_ms"] = tensors_ms / share
+                driver_ids = driver_ids_of[id(t)]
+                if not driver_ids:
+                    continue
+                requests = self._stage_driver_window(
+                    t, driver_ids, snap, seen_apps, pending_supplier
+                )
+                if requests:
+                    staged.append((t, requests))
         if staged:
             solve_started = self._clock()
             views = self._solver.pack_windows_dispatch(
@@ -721,30 +724,34 @@ class SparkSchedulerExtender:
         # steady state it returns the resident epoch-versioned arrays
         # (O(changed), usually O(1)); the capture-before-list versioning
         # dance lives inside the store.
-        featurize_start = self._clock()
-        snap = self.features.snapshot()
-        phases = t.featurize_phases
-        t_snap = self._clock()
-        phases["featurize_snapshot_ms"] = (t_snap - featurize_start) * 1e3
-        # Device-resident state threaded ACROSS windows: the previous
-        # window's committed base (still on device) plus additive external
-        # deltas — what makes dispatch-before-fetch pipelining exact
-        # (solver.build_tensors_pipelined). The statics epoch lets the
-        # builder skip its per-window static-field array compares.
-        tensors = self._solver.build_tensors_pipelined(
-            snap.nodes, snap.usage, snap.overhead,
-            topo_version=snap.nodes_version,
-            statics_version=snap.statics_epoch,
-            roster_rows=snap.roster_rows,
-            dirty_hint=snap.dirty_hint,
-            avail_epoch=snap.avail_epoch,
-            avail_journal=snap.avail_journal,
-        )
-        phases["featurize_tensors_ms"] = (self._clock() - t_snap) * 1e3
-        requests = self._stage_driver_window(
-            t, driver_ids, snap, set(self._inflight_apps),
-            self._pending_driver_supplier(),
-        )
+        from spark_scheduler_tpu.tracing import tracer
+
+        with tracer().span("featurize"):
+            featurize_start = self._clock()
+            snap = self.features.snapshot()
+            phases = t.featurize_phases
+            t_snap = self._clock()
+            phases["featurize_snapshot_ms"] = (t_snap - featurize_start) * 1e3
+            # Device-resident state threaded ACROSS windows: the previous
+            # window's committed base (still on device) plus additive external
+            # deltas — what makes dispatch-before-fetch pipelining exact
+            # (solver.build_tensors_pipelined). The statics epoch lets
+            # build_tensors_pipelined skip its per-window static-field
+            # array compares.
+            tensors = self._solver.build_tensors_pipelined(
+                snap.nodes, snap.usage, snap.overhead,
+                topo_version=snap.nodes_version,
+                statics_version=snap.statics_epoch,
+                roster_rows=snap.roster_rows,
+                dirty_hint=snap.dirty_hint,
+                avail_epoch=snap.avail_epoch,
+                avail_journal=snap.avail_journal,
+            )
+            phases["featurize_tensors_ms"] = (self._clock() - t_snap) * 1e3
+            requests = self._stage_driver_window(
+                t, driver_ids, snap, set(self._inflight_apps),
+                self._pending_driver_supplier(),
+            )
         if not requests:
             return
         t.solve_started = self._clock()
@@ -923,89 +930,92 @@ class SparkSchedulerExtender:
             domains[i] = domain_by_sig[sig]
         t_domains = self._clock()
         phases["featurize_domains_ms"] = (t_domains - t_stage) * 1e3
-        # First non-empty window of the dispatch pays the (memoized)
-        # pending-driver scan here, inside its fifo phase interval.
-        parsed_pending = pending_supplier()
+        from spark_scheduler_tpu.tracing import tracer
 
-        requests: list[WindowRequest] = []
-        kept: list[tuple] = []
-        now_policy = self._clock()
-        for i, pod, res, args in window:
-            rows: list[tuple] = []
-            if self._config.fifo:
-                group = find_instance_group(
-                    pod, self._pod_lister.instance_group_label
-                )
-                if self._policy is not None:
-                    # Policy window ordering (policy/ordering.py): blocker
-                    # rows by the configured strategy; a DRF cross-group
-                    # yield denies without consuming a solve (disjoint
-                    # domains — capacity rows cannot express it).
-                    blockers, hard = self._policy.ordering.blockers(
-                        pod, group, parsed_pending, now_policy
+        with tracer().span("featurize-fifo"):
+            # First non-empty window of the dispatch pays the (memoized)
+            # pending-driver scan here, inside its fifo phase interval.
+            parsed_pending = pending_supplier()
+
+            requests: list[WindowRequest] = []
+            kept: list[tuple] = []
+            now_policy = self._clock()
+            for i, pod, res, args in window:
+                rows: list[tuple] = []
+                if self._config.fifo:
+                    group = find_instance_group(
+                        pod, self._pod_lister.instance_group_label
                     )
-                    if hard:
-                        msg = (
-                            "yielding to instance group with smaller "
-                            "dominant share"
+                    if self._policy is not None:
+                        # Policy window ordering (policy/ordering.py): blocker
+                        # rows by the configured strategy; a DRF cross-group
+                        # yield denies without consuming a solve (disjoint
+                        # domains — capacity rows cannot express it).
+                        blockers, hard = self._policy.ordering.blockers(
+                            pod, group, parsed_pending, now_policy
                         )
-                        self._demands.create_demand_for_application(pod, res)
-                        self._mark_outcome(
-                            pod, ROLE_DRIVER, FAILURE_EARLIER_DRIVER,
-                            timer_start,
-                        )
-                        self._record_decision(
-                            pod, ROLE_DRIVER, FAILURE_EARLIER_DRIVER, None,
-                            args.node_names, msg,
-                        )
-                        results[i] = self._fail(
-                            args, FAILURE_EARLIER_DRIVER, msg
-                        )
-                        continue
-                    for _ed, _ed_group, ed_res, ed_skip in blockers:
-                        rows.append(
-                            (
-                                ed_res.driver_resources,
-                                ed_res.executor_resources,
-                                ed_res.min_executor_count,
-                                ed_skip,
+                        if hard:
+                            msg = (
+                                "yielding to instance group with smaller "
+                                "dominant share"
                             )
-                        )
-                else:
-                    for ed, ed_group, ed_res, ed_skip in parsed_pending:
-                        if not SparkPodLister.is_earlier_driver(
-                            ed, ed_group, pod, group
-                        ):
+                            self._demands.create_demand_for_application(pod, res)
+                            self._mark_outcome(
+                                pod, ROLE_DRIVER, FAILURE_EARLIER_DRIVER,
+                                timer_start,
+                            )
+                            self._record_decision(
+                                pod, ROLE_DRIVER, FAILURE_EARLIER_DRIVER, None,
+                                args.node_names, msg,
+                            )
+                            results[i] = self._fail(
+                                args, FAILURE_EARLIER_DRIVER, msg
+                            )
                             continue
-                        rows.append(
-                            (
-                                ed_res.driver_resources,
-                                ed_res.executor_resources,
-                                ed_res.min_executor_count,
-                                ed_skip,
+                        for _ed, _ed_group, ed_res, ed_skip in blockers:
+                            rows.append(
+                                (
+                                    ed_res.driver_resources,
+                                    ed_res.executor_resources,
+                                    ed_res.min_executor_count,
+                                    ed_skip,
+                                )
                             )
-                        )
-            rows.append(
-                (
-                    res.driver_resources,
-                    res.executor_resources,
-                    res.min_executor_count,
-                    False,
+                    else:
+                        for ed, ed_group, ed_res, ed_skip in parsed_pending:
+                            if not SparkPodLister.is_earlier_driver(
+                                ed, ed_group, pod, group
+                            ):
+                                continue
+                            rows.append(
+                                (
+                                    ed_res.driver_resources,
+                                    ed_res.executor_resources,
+                                    ed_res.min_executor_count,
+                                    ed_skip,
+                                )
+                            )
+                rows.append(
+                    (
+                        res.driver_resources,
+                        res.executor_resources,
+                        res.min_executor_count,
+                        False,
+                    )
                 )
-            )
-            kept.append((i, pod, res, args))
-            requests.append(
-                WindowRequest(
-                    rows=rows,
-                    driver_candidate_names=args.node_names,
-                    domain_node_names=domains[i],
+                kept.append((i, pod, res, args))
+                requests.append(
+                    WindowRequest(
+                        rows=rows,
+                        driver_candidate_names=args.node_names,
+                        domain_node_names=domains[i],
+                    )
                 )
-            )
-        if len(kept) != len(window):
-            window[:] = kept  # t.window stays aligned with `requests`
+            if len(kept) != len(window):
+                window[:] = kept  # t.window stays aligned with `requests`
 
-        now = self._clock()
-        phases["featurize_fifo_ms"] = (now - t_domains) * 1e3
+            now = self._clock()
+            phases["featurize_fifo_ms"] = (now - t_domains) * 1e3
         # The window's featurize cost is the sum of its contiguous phases
         # (shared snapshot/tensor costs arrive as the fused claim's equal
         # shares, so fused sub-windows report their amortized featurize).
@@ -1033,6 +1043,15 @@ class SparkSchedulerExtender:
         requests = t.handle.requests
         window, results, timer_start = t.window, t.results, t.timer_start
         all_nodes, by_name, domains = t.all_nodes, t.by_name, t.domains
+        # The solver's host launch and blocking pull, inside solve_ms.
+        solver_phases = {
+            key: ms
+            for key, ms in (
+                ("dispatch_ms", t.handle.dispatch_ms),
+                ("fetch_wait_ms", t.handle.fetch_wait_ms),
+            )
+            if ms is not None
+        }
         commit_t0 = self._clock()
 
         def record(k, pod, args, outcome, node, msg="", extra=None):
@@ -1043,6 +1062,7 @@ class SparkSchedulerExtender:
                     "featurize_ms": t.featurize_ms,
                     **t.featurize_phases,
                     "solve_ms": solve_ms,
+                    **solver_phases,
                     # The window-coalesced commit: classification + ONE
                     # batched reservation write-back, measured from the
                     # decisions landing on host to this record.
@@ -1067,107 +1087,108 @@ class SparkSchedulerExtender:
                 },
             )
 
-        # Pass 1 — classify: denials finalize immediately (demand +
-        # record + failure response); admitted gangs queue for ONE
-        # coalesced reservation write-back below instead of a cache
-        # write + listener fan-out per decision.
-        admitted: list[tuple] = []  # (k, i, pod, res, args, packing)
-        for k, (i, pod, res, args) in enumerate(window):
-            d = decisions[k]
-            if d.admitted:
-                admitted.append((k, i, pod, res, args, d.packing))
-                continue
-            # Per-request trace span over the decision apply, same
-            # name/tags as the solo path's — dashboards keyed on
-            # select-node cover windowed serving too.
-            with tracer().span(
-                "select-node", role=ROLE_DRIVER,
-                pod=f"{pod.namespace}/{pod.name}",
-            ) as sp:
-                self._demands.create_demand_for_application(pod, res)
-                extra = None
-                if d.earlier_blocked:
-                    outcome, msg = (
-                        FAILURE_EARLIER_DRIVER,
-                        "earlier drivers do not fit to the cluster",
-                    )
-                else:
-                    outcome, msg = (
-                        FAILURE_FIT,
-                        "application does not fit to the cluster",
-                    )
-                    pre = self._try_preempt_for(
-                        pod, res, args.node_names, domains[i]
-                    )
-                    if pre is not None:
-                        # Evictions freed capacity; this round still denies
-                        # and the pod's retry admits against the freed
-                        # cluster (the solo path re-solves inline instead).
-                        msg = (
-                            "application does not fit; preempted "
-                            f"{len(pre['evicted'])} lower-priority gang(s)"
-                        )
-                        extra = {"preemption": pre}
-                sp.tag("outcome", outcome)
-                self._mark_outcome(pod, ROLE_DRIVER, outcome, timer_start)
-                record(k, pod, args, outcome, None, msg, extra)
-                results[i] = self._fail(args, outcome, msg)
-
-        # One batched reservation write-back for the whole window: one
-        # write-mutex hold, one batched usage-tracker/overhead delta
-        # application, one (deferred) queue drain — instead of the full
-        # chain per admitted gang. Per-entry failures surface exactly as
-        # the serial create's ReservationError did.
-        errors = self._rrm.create_reservations_batch(
-            [
-                (pod, res, packing.driver_node, packing.executor_nodes)
-                for _k, _i, pod, res, _args, packing in admitted
-            ]
-        )
-
-        # Pass 2 — finalize admitted gangs against the batch outcome.
-        for (k, i, pod, res, args, packing), err in zip(admitted, errors):
-            with tracer().span(
-                "select-node", role=ROLE_DRIVER,
-                pod=f"{pod.namespace}/{pod.name}",
-            ) as sp:
-                if self._metrics is not None:
-                    self._metrics.report_packing_efficiency(
-                        self.binpacker.name, packing
-                    )
-                    self._metrics.report_cross_zone(
-                        packing.driver_node,
-                        packing.executor_nodes,
-                        all_nodes
-                        if domains[i] is None
-                        else [by_name[nm] for nm in domains[i]],
-                    )
-                self._demands.delete_demand_if_exists(pod)
-                if err is not None:
-                    # No rollback of the window's committed base: later
-                    # window decisions stand even though this app holds
-                    # nothing. That is the reference's own durability
-                    # stance — reservation writes are fire-and-forget and
-                    # "some writes will be lost on leader change"
-                    # (failover.go:35-41); the failed app retries, and
-                    # failover reconciliation repairs drift.
-                    sp.tag("outcome", FAILURE_INTERNAL)
-                    self._mark_outcome(
-                        pod, ROLE_DRIVER, FAILURE_INTERNAL, timer_start
-                    )
-                    record(k, pod, args, FAILURE_INTERNAL, None, str(err))
-                    results[i] = self._fail(args, FAILURE_INTERNAL, str(err))
+        with tracer().span("commit"):
+            # Pass 1 — classify: denials finalize immediately (demand +
+            # record + failure response); admitted gangs queue for ONE
+            # coalesced reservation write-back below instead of a cache
+            # write + listener fan-out per decision.
+            admitted: list[tuple] = []  # (k, i, pod, res, args, packing)
+            for k, (i, pod, res, args) in enumerate(window):
+                d = decisions[k]
+                if d.admitted:
+                    admitted.append((k, i, pod, res, args, d.packing))
                     continue
-                if self._events is not None:
-                    self._events.emit_application_scheduled(pod, res)
-                sp.tag("outcome", SUCCESS)
-                self._mark_outcome(pod, ROLE_DRIVER, SUCCESS, timer_start)
-                record(k, pod, args, SUCCESS, packing.driver_node)
-                results[i] = ExtenderFilterResult(
-                    node_names=[packing.driver_node],
-                    failed_nodes={},
-                    outcome=SUCCESS,
-                )
+                # Per-request trace span over the decision apply, same
+                # name/tags as the solo path's — dashboards keyed on
+                # select-node cover windowed serving too.
+                with tracer().span(
+                    "select-node", role=ROLE_DRIVER,
+                    pod=f"{pod.namespace}/{pod.name}",
+                ) as sp:
+                    self._demands.create_demand_for_application(pod, res)
+                    extra = None
+                    if d.earlier_blocked:
+                        outcome, msg = (
+                            FAILURE_EARLIER_DRIVER,
+                            "earlier drivers do not fit to the cluster",
+                        )
+                    else:
+                        outcome, msg = (
+                            FAILURE_FIT,
+                            "application does not fit to the cluster",
+                        )
+                        pre = self._try_preempt_for(
+                            pod, res, args.node_names, domains[i]
+                        )
+                        if pre is not None:
+                            # Evictions freed capacity; this round still denies
+                            # and the pod's retry admits against the freed
+                            # cluster (the solo path re-solves inline instead).
+                            msg = (
+                                "application does not fit; preempted "
+                                f"{len(pre['evicted'])} lower-priority gang(s)"
+                            )
+                            extra = {"preemption": pre}
+                    sp.tag("outcome", outcome)
+                    self._mark_outcome(pod, ROLE_DRIVER, outcome, timer_start)
+                    record(k, pod, args, outcome, None, msg, extra)
+                    results[i] = self._fail(args, outcome, msg)
+
+            # One batched reservation write-back for the whole window: one
+            # write-mutex hold, one batched usage-tracker/overhead delta
+            # application, one (deferred) queue drain — instead of the full
+            # chain per admitted gang. Per-entry failures surface exactly as
+            # the serial create's ReservationError did.
+            errors = self._rrm.create_reservations_batch(
+                [
+                    (pod, res, packing.driver_node, packing.executor_nodes)
+                    for _k, _i, pod, res, _args, packing in admitted
+                ]
+            )
+
+            # Pass 2 — finalize admitted gangs against the batch outcome.
+            for (k, i, pod, res, args, packing), err in zip(admitted, errors):
+                with tracer().span(
+                    "select-node", role=ROLE_DRIVER,
+                    pod=f"{pod.namespace}/{pod.name}",
+                ) as sp:
+                    if self._metrics is not None:
+                        self._metrics.report_packing_efficiency(
+                            self.binpacker.name, packing
+                        )
+                        self._metrics.report_cross_zone(
+                            packing.driver_node,
+                            packing.executor_nodes,
+                            all_nodes
+                            if domains[i] is None
+                            else [by_name[nm] for nm in domains[i]],
+                        )
+                    self._demands.delete_demand_if_exists(pod)
+                    if err is not None:
+                        # No rollback of the window's committed base: later
+                        # window decisions stand even though this app holds
+                        # nothing. That is the reference's own durability
+                        # stance — reservation writes are fire-and-forget and
+                        # "some writes will be lost on leader change"
+                        # (failover.go:35-41); the failed app retries, and
+                        # failover reconciliation repairs drift.
+                        sp.tag("outcome", FAILURE_INTERNAL)
+                        self._mark_outcome(
+                            pod, ROLE_DRIVER, FAILURE_INTERNAL, timer_start
+                        )
+                        record(k, pod, args, FAILURE_INTERNAL, None, str(err))
+                        results[i] = self._fail(args, FAILURE_INTERNAL, str(err))
+                        continue
+                    if self._events is not None:
+                        self._events.emit_application_scheduled(pod, res)
+                    sp.tag("outcome", SUCCESS)
+                    self._mark_outcome(pod, ROLE_DRIVER, SUCCESS, timer_start)
+                    record(k, pod, args, SUCCESS, packing.driver_node)
+                    results[i] = ExtenderFilterResult(
+                        node_names=[packing.driver_node],
+                        failed_nodes={},
+                        outcome=SUCCESS,
+                    )
 
     def _build_serving_tensors(self, snap):
         """Device tensors for the SOLO serving paths from a feature-store
@@ -1286,7 +1307,10 @@ class SparkSchedulerExtender:
             phases={
                 k: v
                 for k, v in ctx.items()
-                if k in ("featurize_ms", "solve_ms", "commit_ms")
+                if k in (
+                    "featurize_ms", "solve_ms", "commit_ms", "dispatch_ms",
+                    "fetch_wait_ms",
+                )
                 or k.startswith("featurize_")
             },
             solve=solve_info,
@@ -1358,7 +1382,12 @@ class SparkSchedulerExtender:
         if role == ROLE_DRIVER:
             return self._select_driver_node(pod, node_names, ctx=ctx)
         if role == ROLE_EXECUTOR:
-            node, outcome, msg = self._select_executor_node(pod, node_names)
+            from spark_scheduler_tpu.tracing import tracer
+
+            with tracer().span("executor-lookup"):
+                node, outcome, msg = self._select_executor_node(
+                    pod, node_names
+                )
             if outcome in SUCCESS_OUTCOMES:
                 self._demands.delete_demand_if_exists(pod)
             return node, outcome, msg

@@ -979,7 +979,7 @@ class WindowHandle:
         "info", "parts", "request_device", "dispatch_id", "dispatched_at",
         "fused_decisions", "released", "host_tensors", "use_fallback",
         "prune", "fallback_reason", "base_kept", "avail_gen",
-        "avail_note_epoch", "__weakref__",
+        "avail_note_epoch", "dispatch_ms", "fetch_wait_ms", "__weakref__",
     )
 
     def __init__(self, *, strategy, blob, requests, flat_rows, host_avail,
@@ -1067,6 +1067,11 @@ class WindowHandle:
         # journaled as UNKNOWABLE — its fetch patches the entry with the
         # exact commit rows so slot mirrors can cross the epoch.
         self.avail_note_epoch = None
+        # Host milliseconds of the `solve-dispatch` launch, and of the
+        # fetch's blocking wait on the decision pull (`fetch-wait`): the
+        # flight recorder's dispatch_ms / fetch_wait_ms phases.
+        self.dispatch_ms = None
+        self.fetch_wait_ms = None
 
     def release_buffers(self) -> None:
         """Drop the dispatch's staging buffers: the device decision blob
@@ -1139,6 +1144,14 @@ class FusedWindowView:
     def request_device(self):
         rd = self.owner.request_device
         return rd[self.lo:self.hi] if rd is not None else None
+
+    @property
+    def dispatch_ms(self):
+        return self.owner.dispatch_ms
+
+    @property
+    def fetch_wait_ms(self):
+        return self.owner.fetch_wait_ms
 
     # Serving-loop eager-fetch surface (server/http.py eager_futures).
     @property
@@ -3858,7 +3871,7 @@ class PlacementSolver:
                 "solve-dispatch", strategy=strategy, nodes=n,
                 window_requests=len(requests), window_rows=b, batched=True,
                 path=path,
-            ):
+            ) as dispatch_span:
                 # One simulated h2d/dispatch boundary per DISPATCH, on the
                 # dispatcher thread — a fused K-window batch pays this once
                 # where K sequential dispatches pay it K times.
@@ -4005,6 +4018,7 @@ class PlacementSolver:
         handle.seg_map = seg_map  # pallas path: [S,R] blob -> flat rows
         handle.host_tensors = host  # degraded-fallback re-solve inputs
         handle.info = info
+        handle.dispatch_ms = dispatch_span.span.duration_ms
         handle.dispatch_id = info["dispatch_id"]
         handle.dispatched_at = self._clock()
         if pipelined:
@@ -4219,7 +4233,7 @@ class PlacementSolver:
                 "solve-dispatch", strategy=strategy, nodes=n,
                 window_requests=len(requests), window_rows=b, batched=True,
                 path="xla-pruned",
-            ):
+            ) as dispatch_span:
                 _shim("h2d")
                 if gather_reused:
                     idx_dev = ent["idx_dev"]
@@ -4341,6 +4355,7 @@ class PlacementSolver:
         handle.host_tensors = host
         handle.prune = plan
         handle.info = info
+        handle.dispatch_ms = dispatch_span.span.duration_ms
         handle.dispatch_id = info["dispatch_id"]
         handle.dispatched_at = self._clock()
         p["unfetched"].append(handle)
@@ -4895,7 +4910,7 @@ class PlacementSolver:
                 window_requests=len(requests), window_rows=len(drv_arr),
                 batched=True, path="pool",
                 partitions=len(plan) if plan else 1,
-            ):
+            ) as dispatch_span:
                 if plan is None:
                     parts.append(
                         submit_part(
@@ -5040,6 +5055,7 @@ class PlacementSolver:
         handle.request_device = request_device
         handle.host_tensors = host  # slot-failure re-dispatch inputs
         handle.info = info
+        handle.dispatch_ms = dispatch_span.span.duration_ms
         handle.dispatch_id = info["dispatch_id"]
         handle.dispatched_at = self._clock()
         p["unfetched"].append(handle)
@@ -5078,15 +5094,18 @@ class PlacementSolver:
         from spark_scheduler_tpu.tracing import tracer
 
         requests, n = handle.requests, handle.n
-        with tracer().span(
+        trace = tracer()
+        with trace.span(
             "solve", strategy=handle.strategy, nodes=n,
             window_requests=len(requests), batched=True,
         ):
             try:
-                if handle.blob_future is not None:
-                    blob = handle.blob_future.result()
-                else:
-                    blob = _shimmed_device_get(handle.blob)
+                with trace.span("fetch-wait") as wait:
+                    if handle.blob_future is not None:
+                        blob = handle.blob_future.result()
+                    else:
+                        blob = _shimmed_device_get(handle.blob)
+                handle.fetch_wait_ms = wait.span.duration_ms
             except Exception as exc:
                 # The device base embodies this window's (now unknowable)
                 # placements while no reservation was created for them.
@@ -5231,7 +5250,9 @@ class PlacementSolver:
 
         strict_ps = None
         strict_known = False
-        with tracer().span(
+        trace = tracer()
+        handle.fetch_wait_ms = 0.0
+        with trace.span(
             "solve", strategy=handle.strategy, nodes=n,
             window_requests=len(requests), batched=True,
             path="pool", partitions=len(handle.parts),
@@ -5239,7 +5260,9 @@ class PlacementSolver:
             for part_i, part in enumerate(handle.parts):
                 redispatched = False
                 try:
-                    out = part.future.result()
+                    with trace.span("fetch-wait") as wait:
+                        out = part.future.result()
+                    handle.fetch_wait_ms += wait.span.duration_ms
                 except Exception as exc:
                     part.slot.inflight = max(0, part.slot.inflight - 1)
                     if tel is not None:

@@ -218,7 +218,6 @@ class SolverTelemetry:
             apps=str(row_bucket * segment_bucket),
             path=path,
         ).update(min(1.0, rows / denom))
-        self.sync_compile_gauges()
 
     def on_featurize(self, phases: dict, store=None) -> None:
         """One serving window's host-featurize breakdown. `phases` maps
@@ -246,7 +245,6 @@ class SolverTelemetry:
         self.registry.counter(
             SOLO_PACKS, nodes=str(nodes), emax=str(emax)
         ).inc()
-        self.sync_compile_gauges()
 
     # -- fused dispatch ------------------------------------------------------
 

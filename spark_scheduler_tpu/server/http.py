@@ -50,6 +50,7 @@ selected by the `server.transport` install knob:
 from __future__ import annotations
 
 import threading
+import time
 
 # Back-compat re-exports: these lived here before the transport split
 # (kube/apiserver.py wraps its listener with _maybe_wrap_tls; tests import
@@ -172,7 +173,9 @@ class PredicateBatcher:
         self._busy_ttl_s = 2.0
         self._busy_until = 0.0
         self._cv = threading.Condition()
-        self._queue: list[list] = []  # [args, event, result, exception, trace]
+        # [args, event, result, exception, trace, enqueued, claimed, set]:
+        # the last three are perf_counter stamps of the hand-off legs.
+        self._queue: list[list] = []
         # Entries the dispatcher has claimed whose events may not be set
         # yet — what stop() fails when the dispatcher thread is stalled in
         # a blocking fetch against a dead device (join times out but
@@ -197,6 +200,14 @@ class PredicateBatcher:
         # the largest fused batch seen.
         self.fused_dispatches = 0
         self.max_fused_k = 1
+        # Thread hand-off of blocking requests (surfaced at GET /metrics):
+        # seconds from enqueue to the dispatcher's claim, seconds from the
+        # entry's completion to its handler thread running again, and the
+        # requests both legs were summed over.
+        self._handoff_lock = threading.Lock()
+        self.queue_wait_s = 0.0
+        self.wake_wait_s = 0.0
+        self.handoffs = 0
         self._thread = threading.Thread(
             target=self._run, daemon=True, name="predicate-batcher"
         )
@@ -206,10 +217,12 @@ class PredicateBatcher:
         from spark_scheduler_tpu.tracing import tracer
 
         # Carry the handler thread's trace context to the dispatcher.
-        entry = [args, threading.Event(), None, None, tracer().current()]
+        entry = [args, threading.Event(), None, None, tracer().current(),
+                 0.0, 0.0, 0.0]
         with self._cv:
             if self._stopped:
                 raise RuntimeError("scheduler is shutting down")
+            entry[5] = time.perf_counter()
             self._queue.append(entry)
             self._cv.notify()
         if not entry[1].wait(timeout):
@@ -221,6 +234,12 @@ class PredicateBatcher:
             # claimed slot at completion.
             self.abandon(entry)
             raise TimeoutError("predicate window timed out")
+        woke = time.perf_counter()
+        if entry[6] and entry[7]:
+            with self._handoff_lock:
+                self.queue_wait_s += entry[6] - entry[5]
+                self.wake_wait_s += woke - entry[7]
+                self.handoffs += 1
         if entry[3] is not None:
             raise entry[3]
         return entry[2]
@@ -230,7 +249,7 @@ class PredicateBatcher:
         parks. `done(result, exc)` is invoked exactly once — from the
         dispatcher thread on completion, or from the stopping thread at
         shutdown. Returns the queue entry for use with `abandon`."""
-        entry = [args, None, None, None, trace_span]
+        entry = [args, None, None, None, trace_span, 0.0, 0.0, 0.0]
 
         def _fire():
             done(entry[2], entry[3])
@@ -409,6 +428,9 @@ class PredicateBatcher:
                 claim = self._max_window * self._fuse_windows
                 batch = self._queue[:claim]
                 del self._queue[:claim]
+                claimed_at = _time.perf_counter()
+                for entry in batch:
+                    entry[6] = claimed_at
                 if batch and len(self.claim_log) < self.CLAIM_LOG_CAP:
                     self.claim_log.append((
                         len(batch), len(self._queue), len(pending),
@@ -587,6 +609,7 @@ class PredicateBatcher:
             ).update(len(batch))
         for entry, result in zip(batch, results):
             entry[2] = result
+            entry[7] = time.perf_counter()
             entry[1].set()
         self._finish_entries(batch)
         return True
@@ -594,10 +617,17 @@ class PredicateBatcher:
     def _fail_batch(self, batch, exc) -> None:
         for entry in batch:
             entry[3] = exc
+            entry[7] = time.perf_counter()
             entry[1].set()
         self._finish_entries(batch)
 
     def stats(self) -> dict:
+        with self._handoff_lock:
+            handoff = {
+                "queue_wait_s": self.queue_wait_s,
+                "wake_wait_s": self.wake_wait_s,
+                "handoffs": self.handoffs,
+            }
         return {
             "windows_served": self.windows_served,
             "requests_served": self.requests_served,
@@ -606,6 +636,7 @@ class PredicateBatcher:
             "fuse_windows": self._fuse_windows,
             "fused_dispatches": self.fused_dispatches,
             "max_fused_k": self.max_fused_k,
+            **handoff,
             "queue_depth": self.queue_depth(),
             "mean_window": (
                 round(self.requests_served / self.windows_served, 2)

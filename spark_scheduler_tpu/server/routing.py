@@ -662,51 +662,52 @@ class SchedulerRoutes(SyncRoutes):
         from spark_scheduler_tpu.tracing import tracer
 
         s = self._s
-        try:
-            pod, node_names = self._parse_predicate(req)
-        except Exception as exc:
-            return json_response(error_code(exc), {"Error": str(exc)})
-        shed = self._shed_response()
-        if shed is not None:
-            return shed
-        # Fleet mode: the facade routes to the home cluster's own stack
-        # (bypassing this endpoint's batcher — each cluster serializes on
-        # its own worker). `?cluster=N` tags which cluster endpoint the
-        # caller believed it hit; wrong-cluster calls are forwarded and
-        # counted, decisions byte-identical either way.
-        fleet = getattr(s, "fleet", None)
-        if fleet is not None:
-            via = req.q("cluster")
-            with tracer().root_from_headers(
-                req.headers, "predicate", pod=f"{pod.namespace}/{pod.name}"
-            ) as root:
-                try:
+        trace = tracer()
+        # Root span continues the caller's b3 trace context (the
+        # witchcraft tracing middleware slot). It opens before the body
+        # decode, so the decode is part of the request's trace.
+        with trace.root_from_headers(req.headers, "predicate") as root:
+            try:
+                with trace.span("predicate:decode"):
+                    pod, node_names = self._parse_predicate(req)
+            except Exception as exc:
+                root.tag("outcome", "bad-request")
+                return json_response(error_code(exc), {"Error": str(exc)})
+            root.tag("pod", f"{pod.namespace}/{pod.name}")
+            shed = self._shed_response()
+            if shed is not None:
+                root.tag("outcome", "shed")
+                return shed
+            # Fleet mode: the facade routes to the home cluster's own stack
+            # (bypassing this endpoint's batcher — each cluster serializes
+            # on its own worker). `?cluster=N` tags which cluster endpoint
+            # the caller believed it hit; wrong-cluster calls are forwarded
+            # and counted, decisions byte-identical either way.
+            fleet = getattr(s, "fleet", None)
+            exc = None
+            try:
+                if fleet is not None:
+                    via = req.q("cluster")
                     decision = fleet.schedule(
                         pod,
                         node_names or None,
                         via=int(via) if via is not None else None,
                     )
-                except Exception as exc:
+                    result = decision.result
+                    root.tag("cluster", str(decision.cluster))
+                else:
+                    result = s.batcher.submit(
+                        ExtenderArgs(pod=pod, node_names=node_names),
+                        timeout=s.request_timeout_s,
+                    )
+            except Exception as e:
+                exc = e
+            with trace.span("predicate:encode"):
+                if exc is not None:
                     root.tag("outcome", "failure-internal")
                     return self._predicate_err(pod, exc)
-                root.tag("outcome", decision.result.outcome)
-                root.tag("cluster", str(decision.cluster))
-                return self._predicate_ok(pod, decision.result, node_names)
-        # Root span continues the caller's b3 trace context (the
-        # witchcraft tracing middleware slot).
-        with tracer().root_from_headers(
-            req.headers, "predicate", pod=f"{pod.namespace}/{pod.name}"
-        ) as root:
-            try:
-                result = s.batcher.submit(
-                    ExtenderArgs(pod=pod, node_names=node_names),
-                    timeout=s.request_timeout_s,
-                )
-            except Exception as exc:
-                root.tag("outcome", "failure-internal")
-                return self._predicate_err(pod, exc)
-            root.tag("outcome", result.outcome)
-            return self._predicate_ok(pod, result, node_names)
+                root.tag("outcome", result.outcome)
+                return self._predicate_ok(pod, result, node_names)
 
     def _predicate_nowait(self, req: Request, respond, schedule_timeout):
         """Event-loop path: no thread parks. The batcher invokes `done`

@@ -8,13 +8,19 @@ lib pkg/logging/logging.go:23-55). This module provides both natively:
   - `Tracer`: thread-local span stacks; `span()` context manager; b3
     single+multi header extraction/injection (x-b3-traceid / x-b3-spanid /
     x-b3-sampled); finished spans land in a bounded ring buffer (pollable
-    at GET /debug/traces) and optionally as JSON lines in a trace log.
+    at GET /debug/traces).
   - `svc1log`: JSON-line service log with explicit safe-param dicts —
     `pod_safe_params`, `demand_safe_params`, `rr_safe_params` mirror the
     reference's safe-param helpers so log pipelines receive identical keys.
   - JAX profiler hooks: `start_jax_profile(dir)` / `stop_jax_profile()`
     wrap jax.profiler start/stop_trace for the server's /debug/profile
     routes — a captured trace is inspectable with TensorBoard/XProf.
+    While a capture runs, every scoped span (`span()`,
+    `root_from_headers()`) is also a `jax.profiler.TraceAnnotation` of
+    the same name on the same thread, so the program's spans sit on the
+    host plane of the trace, on the device operations' clock. Detached
+    spans (the event-loop transport) are not: a TraceMe is scoped to
+    one thread's stack.
 """
 
 from __future__ import annotations
@@ -70,15 +76,28 @@ class Span:
         }
 
 
+# `jax.profiler.TraceAnnotation` while a start_jax_profile capture runs,
+# else None: the one global a span reads when no capture runs, so the
+# serving path never touches jax.profiler outside a capture.
+_annotate = None
+
+
 class _SpanContext:
+    __slots__ = ("_tracer", "span", "_annotation")
+
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._annotation = None
 
     def tag(self, key: str, value) -> None:
         self.span.tags[key] = value
 
     def __enter__(self) -> "_SpanContext":
+        annotate = _annotate
+        if annotate is not None:
+            self._annotation = annotate(self.span.name)
+            self._annotation.__enter__()
         self.span.start = self._tracer._clock()
         self._tracer._push(self.span)
         return self
@@ -88,6 +107,10 @@ class _SpanContext:
         if exc is not None:
             self.span.tags["error"] = repr(exc)
         self._tracer._pop(self.span)
+        annotation = self._annotation
+        if annotation is not None:
+            self._annotation = None
+            annotation.__exit__(None, None, None)
 
 
 # Span/trace ids need uniqueness, not cryptographic strength — and
@@ -129,11 +152,10 @@ class _AttachedContext:
 class Tracer:
     """Thread-local span stack + bounded finished-span ring buffer."""
 
-    def __init__(self, capacity: int = 512, log_stream=None, clock=time.time):
+    def __init__(self, capacity: int = 512, clock=time.time):
         self._local = threading.local()
         self._finished: collections.deque = collections.deque(maxlen=capacity)
         self._lock = threading.Lock()
-        self._log_stream = log_stream
         self._clock = clock
 
     # -- context management --------------------------------------------------
@@ -155,12 +177,8 @@ class Tracer:
         if stack and stack[-1] is span:
             stack.pop()
         if span.sampled:
-            # Stream write stays under the lock: concurrent handler threads
-            # finishing spans must not interleave JSONL lines.
             with self._lock:
                 self._finished.append(span)
-                if self._log_stream is not None:
-                    self._log_stream.write(json.dumps(span.to_dict()) + "\n")
 
     # -- span creation -------------------------------------------------------
 
@@ -231,8 +249,6 @@ class Tracer:
         if span.sampled:
             with self._lock:
                 self._finished.append(span)
-                if self._log_stream is not None:
-                    self._log_stream.write(json.dumps(span.to_dict()) + "\n")
 
     # -- inspection ----------------------------------------------------------
 
@@ -266,29 +282,33 @@ _profile_lock = threading.Lock()
 _profile_dir: Optional[str] = None
 
 
-def start_jax_profile(log_dir: str) -> bool:
-    """Start a JAX profiler trace into `log_dir` (device + host timelines).
-    Returns False if a trace is already running."""
-    global _profile_dir
+def start_jax_profile(log_dir: str, options=None) -> bool:
+    """Start a JAX profiler trace into `log_dir` (device + host timelines),
+    with the program's spans on its host plane until stop_jax_profile.
+    `options`: a `jax.profiler.ProfileOptions`, or None for jax's
+    defaults. Returns False if a trace is already running."""
+    global _profile_dir, _annotate
     import jax
 
     with _profile_lock:
         if _profile_dir is not None:
             return False
-        jax.profiler.start_trace(log_dir)
+        jax.profiler.start_trace(log_dir, profiler_options=options)
         _profile_dir = log_dir
+        _annotate = jax.profiler.TraceAnnotation
         return True
 
 
 def stop_jax_profile() -> Optional[str]:
     """Stop the running trace; returns its directory (None if not running)."""
-    global _profile_dir
+    global _profile_dir, _annotate
     import jax
 
     with _profile_lock:
         if _profile_dir is None:
             return None
         out, _profile_dir = _profile_dir, None
+        _annotate = None
         # Flag cleared BEFORE stop_trace, and jax's internal profile state
         # force-reset if the flush fails (deleted/unwritable dir): stop_trace
         # skips its own reset() on exception, which would otherwise wedge

@@ -6,6 +6,7 @@ All tests drive a stub extender — host-only, no solver — so the races can
 be staged deterministically with events.
 """
 
+import sys
 import threading
 import time
 
@@ -217,3 +218,57 @@ def test_stop_fails_pending_nowait_entries_via_callback():
     stopper.join(10)
     assert not stopper.is_alive()
     assert len(fired) == 1  # a late dispatcher set() never double-fires
+
+
+def test_handoff_counters_rise_by_one_per_request():
+    """Each blocking request adds exactly one hand-off, and its queue and
+    wake legs only ever add non-negative seconds."""
+    ext = StubExtender()
+    b = PredicateBatcher(ext, max_window=4, hold_ms=0)
+    try:
+        prev = b.stats()
+        assert (prev["handoffs"], prev["queue_wait_s"], prev["wake_wait_s"]) == (
+            0, 0.0, 0.0
+        )
+        for _ in range(20):
+            assert b.submit("x", timeout=5) == "ok"
+            cur = b.stats()
+            assert cur["handoffs"] == prev["handoffs"] + 1
+            assert cur["queue_wait_s"] >= prev["queue_wait_s"] >= 0.0
+            assert cur["wake_wait_s"] >= prev["wake_wait_s"] >= 0.0
+            prev = cur
+    finally:
+        b.stop()
+
+
+def test_handoff_counters_lose_no_update_under_concurrent_handlers():
+    """Many handler threads finishing at once: the counters are summed
+    under a lock, so the hand-off count is exact."""
+    ext = StubExtender()
+    b = PredicateBatcher(ext, max_window=4, hold_ms=0)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        n_threads, per = 16, 25
+        errs = []
+
+        def client():
+            try:
+                for _ in range(per):
+                    assert b.submit("x", timeout=10) == "ok"
+            except Exception as exc:  # pragma: no cover - surfaced below
+                errs.append(exc)
+
+        threads = [threading.Thread(target=client) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        assert not errs, errs
+        stats = b.stats()
+        assert stats["handoffs"] == n_threads * per
+        assert stats["queue_wait_s"] >= 0.0 and stats["wake_wait_s"] >= 0.0
+    finally:
+        sys.setswitchinterval(old)
+        b.stop()

@@ -2,15 +2,20 @@
 
 Covers span structure (predicate -> solve nesting, write-back), b3
 propagation from caller headers, the /debug/traces route, svc1log safe
-params, and the JAX profiler capture producing an artifact.
+params, the JAX profiler capture producing an artifact, and the program's
+spans written into a running capture (and nothing into jax.profiler
+without one).
 """
 
 from __future__ import annotations
 
+import glob
 import http.client
 import io
 import json
 import os
+
+import pytest
 
 from spark_scheduler_tpu.tracing import (
     Svc1Logger,
@@ -198,11 +203,23 @@ class TestServingTrace:
             # one joined trace, continuing the caller's id
             assert {s["traceId"] for s in spans} == {trace_id}
             assert by_name["predicate"]["parentId"] == "ab" * 8
-            # A lone driver rides the WINDOW path: select-node (the
-            # decision apply) and solve (the decision pull) are siblings
-            # under the request's predicate span.
-            assert by_name["select-node"]["parentId"] == by_name["predicate"]["id"]
-            assert by_name["solve"]["parentId"] == by_name["predicate"]["id"]
+            # A lone driver rides the WINDOW path: the body decode, the
+            # window's featurize, solve (the decision pull), commit (the
+            # decisions applied) and the response encode are children of
+            # the request's predicate span; select-node is the decision
+            # apply inside commit, fetch-wait the blocking pull in solve.
+            root_id = by_name["predicate"]["id"]
+            for child in (
+                "predicate:decode", "featurize", "solve-dispatch", "solve",
+                "commit", "predicate:encode",
+            ):
+                assert by_name[child]["parentId"] == root_id, child
+            assert by_name["select-node"]["parentId"] == by_name["commit"]["id"]
+            assert by_name["fetch-wait"]["parentId"] == by_name["solve"]["id"]
+            assert by_name["featurize-fifo"]["parentId"] == by_name["featurize"]["id"]
+            assert by_name["predicate"]["tags"]["pod"] == (
+                f"{pods[0].namespace}/{pods[0].name}"
+            )
             assert by_name["select-node"]["tags"]["outcome"] == "success"
             assert by_name["predicate"]["tags"]["outcome"] == "success"
             assert by_name["solve"]["tags"]["batched"] is True
@@ -329,3 +346,240 @@ class TestJaxProfiler:
             if f.endswith(".xplane.pb") or f.endswith(".trace.json.gz")
         ]
         assert found, f"no trace artifact under {log_dir}"
+
+
+def _serve_one_app(tmp_log_dir=None, options=None):
+    """Boot a small server, warm it with one app, then serve a second
+    app's driver and one executor; with `tmp_log_dir`, the second app is
+    served under a start_jax_profile capture. Returns the driver's
+    flight-recorder record."""
+    from spark_scheduler_tpu.server.app import build_scheduler_app
+    from spark_scheduler_tpu.server.config import InstallConfig
+    from spark_scheduler_tpu.server.http import SchedulerHTTPServer
+    from spark_scheduler_tpu.server.kube_io import pod_to_k8s
+    from spark_scheduler_tpu.store.backend import InMemoryBackend
+    from spark_scheduler_tpu.testing.harness import (
+        INSTANCE_GROUP_LABEL,
+        new_node,
+        static_allocation_spark_pods,
+    )
+
+    backend = InMemoryBackend()
+    names = []
+    for i in range(4):
+        n = new_node(f"n{i}")
+        backend.add_node(n)
+        names.append(n.name)
+    app = build_scheduler_app(
+        backend,
+        InstallConfig(
+            fifo=True, sync_writes=True,
+            instance_group_label=INSTANCE_GROUP_LABEL,
+        ),
+    )
+    server = SchedulerHTTPServer(app, host="127.0.0.1", port=0)
+    server.start()
+    conn = http.client.HTTPConnection("127.0.0.1", server.port, timeout=60)
+
+    def post(pod):
+        conn.request(
+            "POST", "/predicates",
+            body=json.dumps(
+                {"Pod": pod_to_k8s(pod), "NodeNames": names}
+            ).encode(),
+        )
+        return json.loads(conn.getresponse().read())
+
+    try:
+        warm = static_allocation_spark_pods("warm", 1)
+        backend.add_pod(warm[0])
+        assert post(warm[0])["NodeNames"]  # compiles outside the capture
+        pods = static_allocation_spark_pods("traced", 1)
+        for p in pods:
+            backend.add_pod(p)
+        if tmp_log_dir is not None:
+            assert start_jax_profile(tmp_log_dir, options)
+        try:
+            got = post(pods[0])["NodeNames"]
+            assert got
+            backend.bind_pod(pods[0], got[0])
+            assert post(pods[1])["NodeNames"]
+        finally:
+            if tmp_log_dir is not None:
+                assert stop_jax_profile() == tmp_log_dir
+        conn.close()
+    finally:
+        server.stop()
+    (record,) = app.recorder.query(app="traced", role="driver")
+    return record
+
+
+def _nested(inner, outer) -> bool:
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+class TestProfilerBridge:
+    def test_program_spans_land_on_the_capture_host_lines(self, tmp_path):
+        """Under start_jax_profile, the handler thread's line holds the
+        request root around its body decode and response encode, and the
+        dispatcher's line holds the window dispatch, the blocking fetch
+        wait inside its solve, and the executor lookup — all on the
+        profiler's clock, properly nested."""
+        import jax
+        from jax.profiler import ProfileData
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        log_dir = str(tmp_path / "trace")
+        _serve_one_app(log_dir, opts)
+        (path,) = glob.glob(
+            os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")
+        )
+        lines = []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                spans: dict[str, list] = {}
+                for e in line.events:
+                    start = int(e.start_ns)
+                    spans.setdefault(e.name, []).append(
+                        (start, start + int(e.duration_ns))
+                    )
+                lines.append(spans)
+        handler = next(ln for ln in lines if "predicate:decode" in ln)
+        assert len(handler["predicate"]) == 2  # driver, then executor
+        for name in ("predicate:decode", "predicate:encode"):
+            assert len(handler[name]) == 2
+            for iv in handler[name]:
+                assert any(_nested(iv, root) for root in handler["predicate"])
+        dispatcher = next(ln for ln in lines if "solve-dispatch" in ln)
+        assert dispatcher is not handler
+        (wait,) = dispatcher["fetch-wait"]
+        (solve,) = dispatcher["solve"]
+        assert _nested(wait, solve)
+        (lookup,) = dispatcher["executor-lookup"]
+        assert any(_nested(lookup, s) for s in dispatcher["select-node"])
+        for name in ("featurize", "featurize-fifo", "commit"):
+            assert name in dispatcher, name
+
+    def test_window_records_carry_dispatch_and_fetch_wait(self):
+        """The driver's flight-recorder record splits its solve into the
+        host dispatch and the blocking fetch wait, both inside solve_ms."""
+        phases = _serve_one_app()["phases"]
+        assert phases["dispatch_ms"] >= 0 and phases["fetch_wait_ms"] >= 0
+        assert phases["dispatch_ms"] + phases["fetch_wait_ms"] <= (
+            phases["solve_ms"] + 1e-3
+        )
+
+    def test_no_capture_never_calls_jax_profiler(self, monkeypatch, tmp_path):
+        """With no capture running — before any, and after one stopped —
+        spans never construct a TraceAnnotation."""
+        import jax
+
+        class Refused:
+            def __init__(self, *a, **kw):
+                raise AssertionError("TraceAnnotation called without a capture")
+
+        def exercise():
+            t = Tracer()
+            with t.root_from_headers({"b3": "aa-bb-1"}, "root"):
+                with t.span("child"):
+                    with t.span("grandchild"):
+                        pass
+            return [s["name"] for s in t.finished_spans()]
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+        assert exercise() == ["grandchild", "child", "root"]
+        log_dir = str(tmp_path / "trace")
+        assert start_jax_profile(log_dir)
+        assert stop_jax_profile() == log_dir
+        assert exercise() == ["grandchild", "child", "root"]
+
+    def test_capture_wraps_each_span_in_an_annotation(self, monkeypatch, tmp_path):
+        """While a capture runs, every scoped span enters and exits an
+        annotation of its own name, innermost first out."""
+        import jax
+
+        events = []
+
+        class Recorded:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorded)
+        log_dir = str(tmp_path / "trace")
+        assert start_jax_profile(log_dir)
+        try:
+            t = Tracer()
+            with t.root_from_headers({}, "root"):
+                with pytest.raises(ValueError):
+                    with t.span("child"):
+                        raise ValueError("x")
+        finally:
+            assert stop_jax_profile() == log_dir
+        assert events == [
+            ("enter", "root"), ("enter", "child"),
+            ("exit", "child"), ("exit", "root"),
+        ]
+
+
+@pytest.mark.parametrize(
+    "kind,solver_kw",
+    [
+        ("single-device", {}),
+        ("pooled", {"device_pool": 2}),
+        ("pruned", {"prune_top_k": 4, "prune_slack": 0.75}),
+        ("fused", {}),
+    ],
+)
+def test_every_window_path_times_dispatch_and_fetch_wait(kind, solver_kw):
+    """Each solver window path (single device, device pool, pruned
+    top-K, fused views) leaves the host launch and the blocking fetch
+    wait on its handle, from spans of those names."""
+    from spark_scheduler_tpu.core.solver import PlacementSolver, WindowRequest
+    from spark_scheduler_tpu.models.kube import ZONE_LABEL, Node
+    from spark_scheduler_tpu.models.resources import Resources
+
+    nodes = [
+        Node(
+            name=f"n{i:03d}",
+            allocatable=Resources.from_quantities("8", "8Gi", "1", round_up=False),
+            labels={ZONE_LABEL: f"z{i % 2}"},
+        )
+        for i in range(16)
+    ]
+    one = Resources.from_quantities("1", "1Gi")
+    names = [n.name for n in nodes]
+    windows = [
+        [WindowRequest(rows=[(one, one, 2, False)], driver_candidate_names=names,
+                       domain_node_names=None)]
+        for _ in range(2 if kind == "fused" else 1)
+    ]
+    t = set_tracer(Tracer())
+    try:
+        solver = PlacementSolver(use_native=False, **solver_kw)
+        for _ in range(2):  # the second window rides the resident carry
+            tensors = solver.build_tensors_pipelined(nodes, {}, {})
+            if kind == "fused":
+                handles = solver.pack_windows_dispatch("tightly-pack", tensors, windows)
+            else:
+                handles = [solver.pack_window_dispatch("tightly-pack", tensors, windows[0])]
+            for h in handles:
+                (decision,) = solver.pack_window_fetch(h)
+                assert decision.admitted
+                assert h.dispatch_ms is not None and h.dispatch_ms >= 0
+                assert h.fetch_wait_ms is not None and h.fetch_wait_ms >= 0
+        if kind == "pruned":
+            assert h.info["path"] == "xla-pruned"
+        solver.close()
+        names_seen = {s["name"] for s in t.finished_spans()}
+        assert {"solve-dispatch", "solve", "fetch-wait"} <= names_seen
+    finally:
+        set_tracer(Tracer())
