@@ -661,10 +661,11 @@ class SparkSchedulerExtender:
         return tickets
 
     def _parse_pending_drivers(self) -> list[tuple]:
-        """FIFO predecessor scan: one backend list + one annotation parse
-        per pending driver, shared by every request of a window (and by
-        every sub-window of a fused claim — each request then filters the
-        shared snapshot, sparkpods.go:51-77 semantics unchanged)."""
+        """FIFO predecessor rows: one read of the backend's pending-driver
+        index + one annotation parse per pending driver, shared by every
+        request of a window (and by every sub-window of a fused claim —
+        each request then filters the shared snapshot, sparkpods.go:51-77
+        semantics unchanged)."""
         out: list[tuple] = []
         ig_label = self._pod_lister.instance_group_label
         for ed in self._pod_lister.list_pending_drivers():
@@ -684,7 +685,7 @@ class SparkSchedulerExtender:
 
     def _pending_driver_supplier(self):
         """LAZY, memoized form of _parse_pending_drivers for window
-        staging: the O(pending-drivers) scan runs at most once per
+        staging: the O(pending-drivers) read runs at most once per
         dispatch (shared across a fused claim's sub-windows) and ONLY when
         some sub-window actually stages a driver request — a window whose
         members all dedup away (in-flight duplicates, idempotent retries)
@@ -771,7 +772,7 @@ class SparkSchedulerExtender:
         `seen_apps` is MUTATED (the fused claim threads one set across its
         sub-windows, exactly as _inflight_apps threads across pipelined
         dispatches); `pending_supplier` is the lazy shared FIFO pending
-        scan (_pending_driver_supplier), invoked only once a window is
+        read (_pending_driver_supplier), invoked only once a window is
         known non-empty — its cost lands inside this ticket's fifo
         featurize phase."""
         all_nodes, topo = snap.nodes, snap.nodes_version
@@ -1022,7 +1023,7 @@ class SparkSchedulerExtender:
         t.featurize_ms = sum(phases.values())
         tel = self._solver.telemetry
         if tel is not None:
-            tel.on_featurize(phases, self.features)
+            tel.on_featurize(phases, self.features, self._backend)
         return requests
 
     def _complete_driver_window(self, t: WindowTicket) -> None:

@@ -164,24 +164,19 @@ class SparkPodLister:
         self._backend = backend
         self.instance_group_label = instance_group_label
         # Per-app and per-role listing are on the executor/FIFO hot paths;
-        # with an index-capable backend they touch one bucket instead of
-        # scanning every pod (the reference's informer indexers).
-        register = getattr(backend, "register_pod_index", None)
-        if register is not None:
-            register(SPARK_APP_ID_LABEL)
-            register(SPARK_ROLE_LABEL)
+        # they touch one bucket instead of scanning every pod (the
+        # reference's informer indexers). The FIFO queue is read from the
+        # pending-driver index (which registers the role index), so its
+        # cost follows the queue, not the drivers the cluster runs.
+        backend.register_pod_index(SPARK_APP_ID_LABEL)
+        backend.register_pending_driver_index(SPARK_ROLE_LABEL, ROLE_DRIVER)
 
     def list_pending_drivers(self) -> list[Pod]:
-        """All unscheduled, undeleted driver pods, oldest first — ONE backend
-        scan shared by every request of a serving window (the per-request
-        filter in `earlier_of` is then O(pending))."""
-        out = [
-            p
-            for p in self._backend.list_pods(labels={SPARK_ROLE_LABEL: ROLE_DRIVER})
-            if not p.node_name and p.deletion_timestamp is None
-        ]
-        out.sort(key=lambda p: p.creation_timestamp)
-        return out
+        """All unscheduled, undeleted driver pods, oldest first, as a fresh
+        list read from the backend's pending-driver index: O(pending), one
+        read shared by every request of a serving window (the per-request
+        filter in `earlier_of` is then O(pending) too)."""
+        return self._backend.list_pending_drivers()
 
     @staticmethod
     def is_earlier_driver(p: Pod, p_group: Optional[str], driver: Pod,

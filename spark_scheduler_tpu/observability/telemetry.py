@@ -108,6 +108,14 @@ FEATURIZE_USAGE_REFRESHES = (
 FEATURIZE_OVERHEAD_REFRESHES = (
     "foundry.spark.scheduler.solver.featurize.overhead.refreshes"
 )
+# The backend's pending-driver index (store/backend.py): FIFO queue reads
+# served from it, and its full builds (one per process, at registration).
+FEATURIZE_PENDING_INDEX_READS = (
+    "foundry.spark.scheduler.solver.featurize.pending_index_reads"
+)
+FEATURIZE_PENDING_INDEX_BUILDS = (
+    "foundry.spark.scheduler.solver.featurize.pending_index_builds"
+)
 # O(K + changed) tensor build (ISSUE 13): per-window build wall time, rows
 # the DENSE mirror sweep examined (0 in steady state — the fallback), and
 # rows the event-fed dirty-set sync examined instead.
@@ -219,11 +227,12 @@ class SolverTelemetry:
             path=path,
         ).update(min(1.0, rows / denom))
 
-    def on_featurize(self, phases: dict, store=None) -> None:
+    def on_featurize(self, phases: dict, store=None, backend=None) -> None:
         """One serving window's host-featurize breakdown. `phases` maps
         record keys ("featurize_snapshot_ms", ...) to wall ms; `store` is
         the HostFeatureStore whose counters become gauges (how often the
-        roster/usage/overhead actually refreshed vs served resident)."""
+        roster/usage/overhead actually refreshed vs served resident);
+        `backend`'s pending-driver index counters become gauges too."""
         for key, ms in phases.items():
             phase = key[len("featurize_"):]
             if phase.endswith("_ms"):
@@ -239,6 +248,13 @@ class SolverTelemetry:
             )
             self.registry.gauge(FEATURIZE_OVERHEAD_REFRESHES).set(
                 store.overhead_refreshes
+            )
+        if backend is not None:
+            self.registry.gauge(FEATURIZE_PENDING_INDEX_READS).set(
+                backend.pending_index_reads
+            )
+            self.registry.gauge(FEATURIZE_PENDING_INDEX_BUILDS).set(
+                backend.pending_index_builds
             )
 
     def on_pack(self, *, nodes: int, emax: int) -> None:

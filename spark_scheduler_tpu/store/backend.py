@@ -88,6 +88,20 @@ class InMemoryBackend(ClusterBackend):
         # by consumers (SparkPodLister); list_pods uses them when the filter
         # carries an indexed key.
         self._pod_indexes: dict[str, dict[str, dict[tuple[str, str], Pod]]] = {}
+        # Pending-driver index (the FIFO queue): driver pods with no node
+        # and no deletion timestamp, keyed (namespace, name). Registered by
+        # SparkPodLister; None until then, so DurableBackend's replay fills
+        # only the store and the build covers it. Kept on every pod write
+        # after registration: create/update/delete through _pod_index_add
+        # and _pod_index_remove, and bind_pod, the one in-place pod
+        # mutation (KubeBackend's _apply_external and _remove_local write
+        # only the remote CRD kinds, never pods). Its insertion order is
+        # the driver role bucket's restricted to pending pods, because
+        # both go through the same remove/add sequence.
+        self._pending_drivers: Optional[dict[tuple[str, str], Pod]] = None
+        self._pending_role: tuple[str, str] = ("", "")
+        self.pending_index_reads = 0
+        self.pending_index_builds = 0
 
     # -- CRDs ---------------------------------------------------------------
 
@@ -262,8 +276,43 @@ class InMemoryBackend(ClusterBackend):
                     idx.setdefault(v, {})[k] = p
             self._pod_indexes[label_key] = idx
 
+    def register_pending_driver_index(self, role_label: str, driver_role: str) -> None:
+        """Maintain the pending-driver index: pods labelled
+        `role_label=driver_role`, unbound and undeleted. Built once, from
+        the role bucket (registered here if absent), so its order is the
+        bucket's; list_pending_drivers then reads only pending pods."""
+        with self._lock:
+            if self._pending_drivers is not None:
+                return
+            self.register_pod_index(role_label)
+            self._pending_role = (role_label, driver_role)
+            bucket = self._pod_indexes[role_label].get(driver_role, {})
+            self._pending_drivers = {
+                k: p for k, p in bucket.items() if self._is_pending_driver(p)
+            }
+            self.pending_index_builds += 1
+
+    def _is_pending_driver(self, pod: Pod) -> bool:
+        role_label, driver_role = self._pending_role
+        return (
+            pod.labels.get(role_label) == driver_role
+            and not pod.node_name
+            and pod.deletion_timestamp is None
+        )
+
+    def list_pending_drivers(self) -> list[Pod]:
+        """Pending driver pods, oldest first; equal creation times keep the
+        role bucket's order (the stable sort a scan of it would give)."""
+        with self._lock:
+            self.pending_index_reads += 1
+            pods = list(self._pending_drivers.values())
+        pods.sort(key=lambda p: p.creation_timestamp)
+        return pods
+
     def _pod_index_add(self, pod: Pod) -> None:
         k = self._key(pod)
+        if self._pending_drivers is not None and self._is_pending_driver(pod):
+            self._pending_drivers[k] = pod
         for label_key, idx in self._pod_indexes.items():
             v = pod.labels.get(label_key)
             if v is not None:
@@ -271,6 +320,8 @@ class InMemoryBackend(ClusterBackend):
 
     def _pod_index_remove(self, pod: Pod) -> None:
         k = self._key(pod)
+        if self._pending_drivers is not None:
+            self._pending_drivers.pop(k, None)
         for label_key, idx in self._pod_indexes.items():
             v = pod.labels.get(label_key)
             if v is not None:
@@ -314,6 +365,8 @@ class InMemoryBackend(ClusterBackend):
             old = Pod(**{f.name: getattr(cur, f.name) for f in cur.__dataclass_fields__.values()})  # type: ignore[attr-defined]
             cur.node_name = node_name
             cur.phase = phase
+            if self._pending_drivers is not None and not self._is_pending_driver(cur):
+                self._pending_drivers.pop(self._key(cur), None)
             self._on_committed("pods", "update", cur)
         self._fire("pods", "update", old, cur)
         return cur
