@@ -1,7 +1,27 @@
 """One run of one cell: set-up, the measured window, the check, and the
 result line. Everything a cell needs is found by name: its entry in
-BENCHMARK.json, its configuration under configs/, its traffic under
-traffic/, and each per-layer metric's reader under metrics/.
+BENCHMARK.json, its configuration under configs/, the configuration's
+semantics under semantics/ (see semantics/__init__.py), its traffic
+under traffic/, and each per-layer metric's reader under metrics/.
+
+A reader's `read(ctx)` returns its number, or None where it finds
+nothing to read. In a `--trace 1` run ctx holds:
+
+  trace       devtrace.reduce of the device trace (None off a chip);
+  program     spans.reduce_program of the program's spans in the traced
+              window: span means, by role, the solve-wait split, idle
+              time by span;
+  records     the flight recorder's decision records of the window, of
+              every role, oldest first;
+  phases      the driver records' phases, each with its queue position;
+  calls       (role, ms) of each call of the window, as the client timed it;
+  counters    the change of each of served.counters() over the window;
+  nodes, executors, rows_mean, device_kind
+              the cluster's nodes, executors per app, mean rows of a
+              driver window, and the chip.
+
+A reader may name, in `SPANS`, program spans beyond spans.PROGRAM_SPANS
+that it reads.
 """
 
 from __future__ import annotations
@@ -20,10 +40,8 @@ import time
 
 import numpy as np
 
-import check
-import cluster as C
-import reference as R
-from loadgen import LoadGen
+import devtrace
+import spans
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -56,13 +74,23 @@ def metrics_of(bench: dict, cell: str, kind: str) -> list[dict]:
     return [m for m in bench[kind] if cell in m.get("workloads", [cell])]
 
 
-def read_metric(name: str, ctx: dict):
+def load_plugin(kind: str, name: str):
+    """The module `<kind>/<name>.py` of the benchmark: a metric's reader,
+    a configuration's semantics."""
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", os.path.join(HERE, "metrics", f"{name}.py")
+        f"bench_{kind}_{name}", os.path.join(HERE, kind, f"{name}.py")
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def read_metric(name: str, ctx: dict):
+    return load_plugin("metrics", name).read(ctx)
+
+
+def semantics_of(config: dict):
+    return load_plugin("semantics", config.get("semantics", "static_gang"))
 
 
 class CompileCounter:
@@ -133,61 +161,20 @@ def log(**fields) -> None:
     print(json.dumps(fields, sort_keys=True), flush=True)
 
 
-def build_world(config: dict, traffic: dict, seed: int, now: float):
-    """The cluster, the running apps placed by the reference, and the
-    pending drivers, all from the seed. Pods are dated so that the first
-    pending driver was created `pending_age_s` before `now`: the queue is
-    then younger than the unschedulable-pod timeout, as a queue that
-    drains is, and the clock reads no run differently from another."""
-    cl = C.make_cluster(config, seed)
-    gang = C.Gang.from_config(config)
-    ref = R.Reference(cl.alloc, cl.zone, len(cl.zone_names), config["install"]["binpack-algo"], gang)
-    pre = traffic["prefill"]
-    if pre.get("saturate"):
-        n_apps = None
-    else:
-        app_cpu = int(gang.driver[0] + gang.count * gang.executor[0])
-        n_apps = round(pre["cpu_share"] * int(cl.alloc[:, 0].sum()) / app_cpu)
-    placements = R.prefill(ref, n_apps)
-    # Apps end in creation order, so the oldest running app is the first
-    # placed.
-    n_running_pods = len(placements) * (1 + gang.count)
-    factory = C.PodFactory(config, int(now) - int(traffic["pending_age_s"]) - n_running_pods)
-    pods, apps, running = [], [], []
-    for i, p in enumerate(placements):
-        app = f"app-{i:07d}"
-        driver = factory.driver(app)
-        pods.append(C.bound(driver, cl.names[p.driver]))
-        names = [driver["metadata"]["name"]]
-        for k, node in enumerate(p.executors):
-            ex = factory.executor(app, k)
-            pods.append(C.bound(ex, cl.names[node]))
-            names.append(ex["metadata"]["name"])
-        apps.append((names[0], cl.names[p.driver], [cl.names[e] for e in p.executors], names[1:]))
-        running.append((app, names))
-    pending = []
-    for j in range(int(traffic["pending_target"]) if traffic.get("pending_at_start", True) else 0):
-        app = f"app-{len(placements) + j:07d}"
-        driver = factory.driver(app)
-        pods.append(driver)
-        pending.append((app, driver))
-    nodes = [C.node_json(cl, i, config) for i in range(cl.n)]
-    return cl, ref, placements, factory, nodes, pods, apps, running, pending
-
-
 def percentile(vals, q):
     return float(np.percentile(np.asarray(vals, float), q)) if vals else None
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: float,
              require_tpu: bool = True, overrides: dict | None = None,
-             tamper=None) -> dict:
+             tamper=None, ctx_out: dict | None = None) -> dict:
     """One run. `overrides` replaces keys of the configuration, under
     "traffic" keys of the traffic (a test's small cluster and short
     queue), and under "program" keys of the install that the program
     alone gets, the reference keeping the configuration's (a control);
-    `tamper(served)` plants a fault in the booted program. Returns the
-    result line's object."""
+    `tamper(served)` plants a fault in the booted program; a traced run
+    copies its readers' ctx into `ctx_out` (spans.py reads it). Returns
+    the result line's object."""
     import jax
 
     devices = jax.devices()
@@ -206,10 +193,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
             program = val
         else:
             config[key] = val if not isinstance(val, dict) else {**config.get(key, {}), **val}
+    sem = semantics_of(config)
+    readers = [(m, load_plugin("metrics", m["name"])) for m in metrics_of(bench, name, "per_layer")]
     counter = CompileCounter()
     t_import = time.time()
 
-    cl, ref, placements, factory, nodes, pods, apps, running, pending = build_world(config, traffic, seed, t_start)
+    world = sem.build_world(config, traffic, seed, t_start)
     t_world = time.time()
 
     sys.path.insert(0, ROOT)
@@ -217,8 +206,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
 
     install = {**config["install"], **program}
     install["jax-compilation-cache-dir"] = os.path.join(CACHE_DIR, "jax")
-    served = S.Served(install, config.get("runtime", {}), nodes, pods, apps)
-    del nodes, pods
+    served = S.Served(install, config.get("runtime", {}), world.nodes, world.pods, world.apps)
+    world.nodes = world.pods = None
     t_boot = time.time()
     if tamper is not None:
         tamper(served)
@@ -226,7 +215,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
     annotate = None
     if trace:
         annotate = jax.profiler.TraceAnnotation
-    gen = LoadGen(cl, config, traffic, factory, served, running, pending, annotate=annotate)
+    gen = sem.make_generator(world, config, traffic, served, annotate)
     try:
         warm = int(traffic["warmup_cycles"])
         cycles = 0
@@ -250,19 +239,21 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
         log(phase="setup", setup_s=setup_s, import_s=t_import - t_start,
             world_s=world_s, boot_s=t_boot - t_world,
             warmup_s=time.time() - t_boot, warmup_cycles=cycles, warmup_quiet=quiet,
-            running_apps=len(placements), pending=len(pending),
+            running_apps=len(world.running), pending=len(world.pending),
             compiles=c0[0], compile_s=counter.compile_s)
 
         trace_dir = None
         window = min(seconds, TRACE_SECONDS) if trace else seconds
         with contextlib.ExitStack() as stack:
             if trace:
+                counters0 = served.counters()
                 trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0
-                jax.profiler.start_trace(trace_dir, profiler_options=opts)
-                stack.callback(jax.profiler.stop_trace)
-                stack.enter_context(jax.profiler.TraceAnnotation("bench:window"))
+                # The program's own capture: its spans land in the trace.
+                served.start_profile(trace_dir, opts)
+                stack.callback(served.stop_profile)
+                stack.enter_context(jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN))
             probe = HostProbe()
             probe.start()
             t_w0 = time.perf_counter()
@@ -283,28 +274,37 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
         for d in devices[: int(cell["chips"])]:
             stats = d.memory_stats() or {}
             peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-        phases, lost = served.driver_phases(seq0) if trace else ([], 0)
-        invariants = served.invariants(gen.admitted)
+        if trace:
+            counters1 = served.counters()
+            records, lost = served.records(seq0)
+        invariants = served.invariants(gen.expected_reservations())
     finally:
         gen.close()
         served.stop()
     del served
 
-    red = None
+    red = prog = None
     if trace:
-        import devtrace
-
+        names = set(spans.PROGRAM_SPANS).union(
+            getattr(sem, "SPANS", ()), *(getattr(mod, "SPANS", ()) for _, mod in readers))
         try:
-            red = devtrace.reduce(devtrace.load(devtrace.find_xplane(trace_dir)), WINDOW_PROGRAM)
+            path = devtrace.find_xplane(trace_dir)
+            events = devtrace.load(path)
+            # The CPU backend writes no device plane (a test's run).
+            if events["devices"] or require_tpu:
+                red = devtrace.reduce(events, WINDOW_PROGRAM)
+            prog = spans.reduce_program(events, spans.load_program(path, names))
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
-        log(phase="trace", program_runs=red["program_runs"], recorder_lost=lost,
-            driver_records=len(phases))
+        phases = [dict(r["phases"], queue_position=r["queue_position"])
+                  for r in records if r["role"] == "driver" and r["phases"]]
+        log(phase="trace", program_runs=red and red["program_runs"], recorder_lost=lost,
+            records=len(records), driver_records=len(phases),
+            program_spans=sum(prog["span_count"].values()))
 
     t_ref = time.time()
-    running_p = [(running[i][0], placements[i]) for i in range(len(placements))]
     try:
-        out = check.replay(ref, cl.names, running_p, [a for a, _ in pending], gen.log, gen.bodies)
+        out = sem.replay(world, gen.log, gen.bodies)
     except (RuntimeError, ValueError, KeyError) as exc:
         out = {"driver_mismatches": 1, "executor_mismatches": 0, "denials_bad_map": 0,
                "reference_overcommitted_nodes": 0, "replay_error": repr(exc)}
@@ -325,6 +325,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
         "compiles_in_window": compiles_in_window,
         "traces_in_window": traces_in_window,
     }
+    extra = out.get("checks", {})
+    if set(extra) & set(checks):
+        raise ValueError(f"semantics checks {sorted(set(extra) & set(checks))} shadow the harness's")
+    checks.update(extra)
     correct = all(v == 0 for v in checks.values())
 
     drivers = [ms for role, ms, w in in_window if role == "driver"]
@@ -344,13 +348,17 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
     else:
         rows = [p["queue_position"] + 1 for p in phases if p.get("queue_position") is not None]
         ctx = {
-            "phases": phases, "trace": red, "nodes": cl.n,
-            "executors": int(config["gang"]["executors"]),
+            "phases": phases, "trace": red, "program": prog, "records": records,
+            "calls": [(role, ms) for role, ms, _ in in_window],
+            "counters": {k: v - counters0.get(k, 0) for k, v in counters1.items()},
+            "nodes": world.n_nodes, "executors": world.executors,
             "rows_mean": statistics.fmean(rows) if rows else None,
             "device_kind": dev.device_kind,
         }
-        for m in metrics_of(bench, name, "per_layer"):
-            v = read_metric(m["name"], ctx)
+        if ctx_out is not None:
+            ctx_out.update(ctx)
+        for m, mod in readers:
+            v = mod.read(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     device = {
@@ -364,9 +372,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
         "metrics": metrics,
         "device": device,
     }
-    if trace:
+    if red is not None:
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
-        result["breakdown"] = {"device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"]}
+        result["breakdown"] = {"device_ops": red["device_ops"], "idle_gaps": prog["idle_gaps"]}
     result["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
     return result

@@ -73,6 +73,11 @@ class LoadGen:
     def close(self) -> None:
         self._conn.close()
 
+    def expected_reservations(self) -> dict[str, tuple[str, list[str]]]:
+        """Apps admitted in this run and still running: (driver node,
+        executor nodes) each was admitted with."""
+        return self.admitted
+
     # --------------------------------------------------------- the world
 
     def _watch(self, fn, *args) -> None:

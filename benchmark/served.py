@@ -23,7 +23,7 @@ from spark_scheduler_tpu.server.kube_io import node_from_k8s, pod_from_k8s
 from spark_scheduler_tpu.core.sparkpods import spark_resources
 from spark_scheduler_tpu.store.backend import DEMAND_CRD, InMemoryBackend
 from spark_scheduler_tpu.testing.harness import overcommit_violations
-from spark_scheduler_tpu.tracing import svc1log
+from spark_scheduler_tpu.tracing import start_jax_profile, stop_jax_profile, svc1log
 
 
 class Served:
@@ -38,7 +38,7 @@ class Served:
         pod names) of every running app."""
         svc1log().set_level(runtime.get("logging-level", "INFO"))
         self.config = InstallConfig.from_dict(install)
-        registry = MetricRegistry()
+        registry = self.registry = MetricRegistry()
         backend = self.backend = InMemoryBackend()
         backend.register_crd(DEMAND_CRD)
         for raw in nodes:
@@ -80,6 +80,11 @@ class Served:
     def bind(self, name: str, node: str) -> None:
         self.backend.bind_pod(self.backend.get("pods", self.namespace, name), node)
 
+    def delete_pod(self, name: str) -> None:
+        """One pod ends and is deleted, as an executor that was scaled
+        down or lost."""
+        self.backend.delete("pods", self.namespace, name)
+
     def complete(self, app_id: str, pod_names: list[str]) -> None:
         """The app's pods end and are deleted, and garbage collection
         deletes the reservation they owned."""
@@ -94,29 +99,66 @@ class Served:
         slots = rr.spec.reservations
         return slots["driver"].node, [r.node for k, r in slots.items() if k != "driver"]
 
+    def soft_reservation(self, app_id: str) -> dict[str, str]:
+        """Node of each soft-reserved executor of the app, by pod name:
+        the extra executors dynamic allocation placed beyond its gang."""
+        soft, found = self.app.soft_store.get_soft_reservation(app_id)
+        return {pod: r.node for pod, r in soft.reservations.items()} if found else {}
+
     # ------------------------------------------------------ readings
 
     def recorder_seq(self) -> int:
         return self.app.recorder.total_recorded
 
-    def driver_phases(self, since: int) -> tuple[list[dict], int]:
-        """Phases of the driver decisions recorded after `since`, and how
-        many records of that span the ring no longer holds."""
+    def records(self, since: int) -> tuple[list[dict], int]:
+        """The flight recorder's decision records of every role after
+        `since`, oldest first, and how many of that span the ring no
+        longer holds."""
         rec = self.app.recorder
         stats = rec.stats()
         lost = max(0, (stats["total_recorded"] - since) - stats["size"])
-        out = [
-            dict(r["phases"], queue_position=r["queue_position"])
-            for r in rec.query(role="driver", limit=rec.capacity)
-            if r["seq"] > since and r["phases"]
-        ]
-        return out, lost
+        return rec.query(limit=rec.capacity, since_seq=since)[::-1], lost
 
-    def invariants(self, admitted: dict[str, tuple[str, list[str]]]) -> dict:
+    def counters(self) -> dict[str, float]:
+        """The program's counters now, one number per series: its metric
+        registry (the solver's telemetry publishes there, compile gauges
+        synced as a scrape of /metrics syncs them; a histogram gives its
+        count and sum) and the batcher's and recorder's stats, under the
+        names /metrics gives them."""
+        self.app.solver.telemetry.sync_compile_gauges()
+        out: dict[str, float] = {}
+        for name, entries in self.registry.snapshot().items():
+            for e in entries:
+                key = name + "".join(f";{k}={v}" for k, v in sorted(e["tags"].items()))
+                if e["kind"] == "histogram":
+                    out[key + ".count"] = e["count"]
+                    out[key + ".sum"] = e["sum"]
+                else:
+                    out[key] = e["value"]
+        for prefix, stats in (
+            ("foundry.spark.scheduler.predicate.batcher.", self.server.batcher.stats()),
+            ("foundry.spark.scheduler.recorder.", self.app.recorder.stats()),
+        ):
+            for k, v in stats.items():
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    out[prefix + k] = v
+        return out
+
+    def start_profile(self, log_dir: str, options) -> None:
+        """Start a device trace through the program's own capture, which
+        writes the program's spans into it beside the device's work."""
+        if not start_jax_profile(log_dir, options):
+            raise RuntimeError("a profile is already running")
+
+    def stop_profile(self) -> None:
+        stop_jax_profile()
+
+    def invariants(self, expected: dict[str, tuple[str, list[str]]]) -> dict:
         """Program-side guarantees: no node over-committed by bound pods
-        or by the scheduler's reservation accounting, and every app
-        admitted in this run and still running holds the reservation it
-        was admitted with, with its bound executors on nodes it holds."""
+        or by the scheduler's reservation accounting, and every app the
+        load generator expects to hold a reservation (app -> driver node,
+        executor nodes) holds that one, with its bound executors on nodes
+        it holds."""
         import numpy as np
 
         nodes = self.backend.list_nodes()
@@ -129,7 +171,7 @@ class Served:
         bound_over = int(np.any(used > alloc, axis=1).sum())
         reserved_over = len(overcommit_violations(self.app, self.backend))
         off = 0
-        for app_id, (driver_node, exec_nodes) in admitted.items():
+        for app_id, (driver_node, exec_nodes) in expected.items():
             held = self.reservation(app_id)
             bound = collections.Counter(
                 p.node_name

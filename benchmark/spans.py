@@ -2,10 +2,10 @@
 
     python3 benchmark/spans.py --workload <name> --seeds 1,2,3 [--seconds 4]
 
-Runs the cell as run.py does, and profiles its measured window through
+Runs the cell as `run.py --trace 1` does, whose capture goes through
 the program's own `start_jax_profile`, which writes the program's trace
 spans (`predicate`, `predicate:decode`, `solve-dispatch`, `fetch-wait`,
-...) into the capture beside the device operations. Prints one line per
+...) into it beside the device operations. Prints one line per
 seed, `SPANS ` and a JSON object: `correct`, the client's driver and
 executor p50, the mean of each program span, the batcher's thread
 hand-off, the solve-wait and executor-call accounts, the device's idle
@@ -19,10 +19,8 @@ The benchmark's own runs never run this.
 
 import argparse
 import bisect
-import inspect
 import json
 import os
-import shutil
 import statistics
 import sys
 import tempfile
@@ -46,9 +44,9 @@ PROGRAM_SPANS = (
 GAPS = 10
 
 
-def load_program(path: str) -> list[tuple[str, int, int, int]]:
+def load_program(path: str, names=PROGRAM_SPANS) -> list[tuple[str, int, int, int]]:
     """(name, thread line, start ns, end ns) of every program span in the
-    trace's host planes."""
+    trace's host planes: each event named in `names`."""
     from jax.profiler import ProfileData
 
     out = []
@@ -59,7 +57,7 @@ def load_program(path: str) -> list[tuple[str, int, int, int]]:
         for line in plane.lines:
             line_no += 1
             for e in line.events:
-                if e.name in PROGRAM_SPANS:
+                if e.name in names:
                     s = int(e.start_ns)
                     out.append((e.name, line_no, s, s + int(e.duration_ns)))
     return out
@@ -278,71 +276,23 @@ def _p50(vals):
 
 
 def run_seed(harness, name: str, seed: int, seconds: float, **run_kw) -> dict:
-    """One run of the cell, its window profiled with the program's spans
-    bridged into the capture where the program has the bridge. `run_kw`
-    goes to harness.run_cell (a test's small cluster)."""
-    import jax
-    from spark_scheduler_tpu import tracing
-    from loadgen import LoadGen
-
-    bridge = "options" in inspect.signature(tracing.start_jax_profile).parameters
-    got: dict = {}
-    run_until = LoadGen.run_until
-
-    def traced_run_until(gen, deadline):
-        served = gen.served
-        batcher = served.server.batcher
-        got["gen"] = gen
-        got["stats0"] = batcher.stats()
-        seq0 = served.recorder_seq()
-        gen._annotate = jax.profiler.TraceAnnotation  # the client's spans
-        log_dir = got["dir"] = tempfile.mkdtemp(prefix="bench-spans-")
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        if bridge:
-            tracing.start_jax_profile(log_dir, opts)
-        else:
-            jax.profiler.start_trace(log_dir, profiler_options=opts)
-        try:
-            with jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN):
-                t_end = run_until(gen, deadline)
-        finally:
-            if bridge:
-                tracing.stop_jax_profile()
-            else:
-                jax.profiler.stop_trace()
-        got["stats1"] = batcher.stats()
-        got["phases"] = served.driver_phases(seq0)[0]
-        return t_end
-
-    LoadGen.run_until = traced_run_until
-    try:
-        res = harness.run_cell(name, seed, seconds, False, t_start=time.time(), **run_kw)
-    finally:
-        LoadGen.run_until = run_until
-    try:
-        path = devtrace.find_xplane(got["dir"])
-        events = devtrace.load(path)
-        # The CPU backend writes no device plane: nothing is idle there.
-        red = devtrace.reduce(events, harness.WINDOW_PROGRAM) if events["devices"] else None
-        prog = reduce_program(events, load_program(path))
-    finally:
-        shutil.rmtree(got["dir"], ignore_errors=True)
-    calls = [c for c in got["gen"].calls if c[2]]
-    phases = got["phases"]
-    s0, s1 = got["stats0"], got["stats1"]
+    """One `--trace 1` run of the cell, whose capture holds the program's
+    spans, reduced further than its readers go. `run_kw` goes to
+    harness.run_cell (a test's small cluster)."""
+    ctx: dict = {}
+    res = harness.run_cell(name, seed, seconds, True, t_start=time.time(), ctx_out=ctx, **run_kw)
+    red, prog, calls, phases = ctx["trace"], ctx["program"], ctx["calls"], ctx["phases"]
+    batcher = {k.rsplit(".", 1)[1]: v for k, v in ctx["counters"].items()
+               if k.startswith("foundry.spark.scheduler.predicate.batcher.")}
     handoff_ms = None
-    if "handoffs" in s1 and s1["handoffs"] > s0["handoffs"]:
-        handoff_ms = 1e3 * (
-            (s1["queue_wait_s"] - s0["queue_wait_s"]) + (s1["wake_wait_s"] - s0["wake_wait_s"])
-        ) / (s1["handoffs"] - s0["handoffs"])
+    if batcher.get("handoffs", 0) > 0:
+        handoff_ms = 1e3 * (batcher["queue_wait_s"] + batcher["wake_wait_s"]) / batcher["handoffs"]
     span_ms = prog["span_ms"]
     out = {
         "seed": seed,
-        "bridge": bridge,
         "correct": res["correct"],
-        "driver_p50_ms": _p50([ms for role, ms, _ in calls if role == "driver"]),
-        "executor_p50_ms": _p50([ms for role, ms, _ in calls if role == "executor"]),
+        "driver_p50_ms": _p50([ms for role, ms in calls if role == "driver"]),
+        "executor_p50_ms": _p50([ms for role, ms in calls if role == "executor"]),
         "calls": len(calls),
         "handoff_ms": handoff_ms,
         "solve_wait_ms": _mean([p["solve_ms"] for p in phases if "solve_ms" in p]),

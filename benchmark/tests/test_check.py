@@ -11,7 +11,7 @@ import harness
 
 # A small cluster, and a short queue so that a CPU run sees many cycles.
 SMALL = {"nodes": 120, "traffic": {"pending_target": 8}}
-CELLS = ["tp5k-serial", "tp5k-fifo-backlog", "sazmf10k-fifo-backlog"]
+CELLS = ["tp5k-serial", "tp5k-fifo-backlog", "sazmf10k-fifo-backlog", "sazmf10k-serial"]
 
 
 def run(cell, seed, overrides=None, tamper=None):
@@ -33,6 +33,9 @@ CONTROL_CASES = [
     ("sazmf10k-fifo-backlog", "fifo-off"),
     ("tp5k-serial", "executor-slot-order"),
     ("tp5k-serial", "distribute-evenly"),
+    # Not executor-slot-order here: minimal fragmentation puts an app's
+    # executors on one node, where every slot order binds alike.
+    ("sazmf10k-serial", "distribute-evenly"),
 ]
 
 
