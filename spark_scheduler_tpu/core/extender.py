@@ -343,7 +343,7 @@ class SparkSchedulerExtender:
                 pod, role, FAILURE_INTERNAL, None, args.node_names, msg
             )
             return self._fail(args, FAILURE_INTERNAL, msg)
-        self._rrm.compact_dynamic_allocation_applications()
+        self._compact()
 
         ctx: dict = {}
         with tracer().span(
@@ -428,7 +428,7 @@ class SparkSchedulerExtender:
             ]
             t.done = True
             return t
-        self._rrm.compact_dynamic_allocation_applications()
+        self._compact()
         t.results = [None] * len(args_list)
         t.roles = [a.pod.labels.get(SPARK_ROLE_LABEL, "") for a in args_list]
         driver_ids = [i for i, r in enumerate(t.roles) if r == ROLE_DRIVER]
@@ -591,7 +591,7 @@ class SparkSchedulerExtender:
                 ]
                 t.done = True
             return tickets
-        self._rrm.compact_dynamic_allocation_applications()
+        self._compact()
         for t in live:
             t.timer_start = timer_start
             t.results = [None] * len(t.args_list)
@@ -1377,6 +1377,15 @@ class SparkSchedulerExtender:
                     self._reconciler.sync_resource_reservations_and_demands()
         self._last_request = now
 
+    def _compact(self) -> None:
+        """Soft-reserved executors take over hard slots their apps' dead
+        executors freed, before the request is served; a pass that
+        drained an app feeds the compaction metrics."""
+        t0 = time.perf_counter()
+        moved = self._rrm.compact_dynamic_allocation_applications()
+        if moved is not None and self._metrics is not None:
+            self._metrics.mark_compaction(time.perf_counter() - t0, moved)
+
     def _select_node(
         self, role: str, pod: Pod, node_names: list[str], ctx=None
     ) -> tuple[Optional[str], str, str]:
@@ -1387,7 +1396,7 @@ class SparkSchedulerExtender:
 
             with tracer().span("executor-lookup"):
                 node, outcome, msg = self._select_executor_node(
-                    pod, node_names
+                    pod, node_names, ctx=ctx
                 )
             if outcome in SUCCESS_OUTCOMES:
                 self._demands.delete_demand_if_exists(pod)
@@ -1711,7 +1720,7 @@ class SparkSchedulerExtender:
             key = (pod.namespace, pod.labels.get(SPARK_APP_ID_LABEL, ""))
             by_app.setdefault(key, []).append(i)
 
-        stragglers: list[dict] = []
+        reschedules: list[tuple[int, tuple[str, str], bool]] = []
         straggler_by_pod: dict[tuple[str, str], dict] = {}
         dup_waiters: dict[tuple[str, str], list[int]] = {}
         deferred_no_spots: dict[tuple[str, str], list[int]] = {}
@@ -1745,95 +1754,17 @@ class SparkSchedulerExtender:
                         (pod.namespace, pod.name), []
                     ).append(i)
                 else:  # reschedule
-                    ctx = app_ctx.get(key)
-                    if ctx is None:
-                        ctx = app_ctx[key] = self._reschedule_context(pod)
-                    pod_key = (pod.namespace, pod.name)
-                    if ctx[0] is None:
-                        finish(i, None, FAILURE_INTERNAL, ctx[2])
-                        straggler_by_pod[pod_key] = {
-                            "result": ("internal", ctx[2])
-                        }
-                        continue
-                    exec_res, zone, _ = ctx
-                    names = [
-                        n.name
-                        for name in args_list[i].node_names
-                        if (n := self._backend.get_node(name)) is not None
-                        and (zone is None or n.zone == zone)
-                    ]
-                    entry = {
-                        "i": i, "key": key, "exec_res": exec_res,
-                        "zone": zone, "names": names, "is_extra": not val,
-                        "result": None,
-                    }
-                    stragglers.append(entry)
-                    straggler_by_pod[pod_key] = entry
-        # Solve stragglers in ARRIVAL order: pack_window commits segment
-        # placements sequentially, so under capacity contention the earlier
-        # request must win the spot exactly as serial serving would.
-        stragglers.sort(key=lambda s: s["i"])
+                    reschedules.append((i, key, not val))
 
         app_failed: set[tuple[str, str]] = set()
         app_internal: dict[tuple[str, str], str] = {}
-        if stragglers:
-            from spark_scheduler_tpu.models.resources import Resources as _R
-
-            tensors = self._build_serving_tensors(self.features.snapshot())
-            decisions = self._solver.pack_window(
-                "tightly-pack",
-                tensors,
-                [
-                    WindowRequest(
-                        rows=[(_R.zero(), s["exec_res"], 1, False)],
-                        driver_candidate_names=s["names"],
-                        domain_node_names=s["names"],
-                    )
-                    for s in stragglers
-                ],
-            )
-            rescheduled = False
-            for s, d in zip(stragglers, decisions):
-                i = s["i"]
-                pod = args_list[i].pod
-                if d.admitted and d.packing.executor_nodes:
-                    node = d.packing.executor_nodes[0]
-                    try:
-                        self._rrm.reserve_for_executor_on_rescheduled_node(
-                            pod, node
-                        )
-                    except ReservationError as exc:
-                        msg = f"failed to reserve node for rescheduled executor: {exc}"
-                        finish(i, None, FAILURE_INTERNAL, msg)
-                        s["result"] = ("internal", msg)
-                        # NOT app_failed: capacity exists (the solve
-                        # admitted); a serial re-attempt by a later same-app
-                        # executor would hit the same write failure, so
-                        # those fail internal below, not failure-fit.
-                        app_internal[s["key"]] = msg
-                        continue
-                    rescheduled = True
-                    s["result"] = ("ok", node)
-                    finish(
-                        i, node,
-                        SUCCESS_SCHEDULED_EXTRA_EXECUTOR
-                        if s["is_extra"]
-                        else SUCCESS_RESCHEDULED,
-                    )
-                else:
-                    self._demands.create_demand_for_executor(
-                        pod, s["exec_res"], zone=s["zone"]
-                    )
-                    s["result"] = ("fit", None)
-                    finish(
-                        i, None, FAILURE_FIT,
-                        "not enough capacity to reschedule the executor",
-                    )
-                    app_failed.add(s["key"])
-            if rescheduled:
-                # New usage on nodes the reservations did not cover: stale
-                # in-flight windows must re-solve (one bump covers the run).
-                self._capacity_epoch += 1
+        if reschedules:
+            reschedules.sort()
+            with tracer().span("executor-reschedule"):
+                self._serve_stragglers(
+                    t, reschedules, finish, app_ctx, straggler_by_pod,
+                    app_failed, app_internal,
+                )
 
         # Duplicate submissions resolve from their first occurrence: success
         # means the bind has applied, so the serial rung 1 would now return
@@ -1900,6 +1831,108 @@ class SparkSchedulerExtender:
                         "application has no free executor spots to schedule this one",
                     )
 
+    def _serve_stragglers(
+        self, t: WindowTicket, reschedules, finish, app_ctx,
+        straggler_by_pod, app_failed, app_internal,
+    ) -> None:
+        """The reschedule rung of _serve_executor_window: per straggler the
+        app's context and its candidates, then ONE grouped solve and the
+        writes. `reschedules` holds (request index, app key, is extra) in
+        arrival order; the results land in the caller's maps."""
+        from spark_scheduler_tpu.models.resources import Resources as _R
+
+        args_list = t.args_list
+        stragglers: list[dict] = []
+        for i, key, is_extra in reschedules:
+            pod = args_list[i].pod
+            ctx = app_ctx.get(key)
+            if ctx is None:
+                ctx = app_ctx[key] = self._reschedule_context(pod)
+            pod_key = (pod.namespace, pod.name)
+            if ctx[0] is None:
+                finish(i, None, FAILURE_INTERNAL, ctx[2])
+                straggler_by_pod[pod_key] = {"result": ("internal", ctx[2])}
+                continue
+            exec_res, zone, _ = ctx
+            names = [
+                n.name
+                for name in args_list[i].node_names
+                if (n := self._backend.get_node(name)) is not None
+                and (zone is None or n.zone == zone)
+            ]
+            entry = {
+                "i": i, "key": key, "exec_res": exec_res,
+                "zone": zone, "names": names, "is_extra": is_extra,
+                "result": None,
+            }
+            stragglers.append(entry)
+            straggler_by_pod[pod_key] = entry
+        if not stragglers:
+            return
+        # Solved in ARRIVAL order (`reschedules` is): pack_window commits
+        # segment placements sequentially, so under capacity contention
+        # the earlier request wins the spot exactly as serial serving
+        # would.
+        tensors = self._build_serving_tensors(self.features.snapshot())
+        decisions = self._solver.pack_window(
+            "tightly-pack",
+            tensors,
+            [
+                WindowRequest(
+                    rows=[(_R.zero(), s["exec_res"], 1, False)],
+                    driver_candidate_names=s["names"],
+                    domain_node_names=s["names"],
+                )
+                for s in stragglers
+            ],
+        )
+        rescheduled = False
+        for s, d in zip(stragglers, decisions):
+            i = s["i"]
+            pod = args_list[i].pod
+            if d.admitted and d.packing.executor_nodes:
+                node = d.packing.executor_nodes[0]
+                try:
+                    self._rrm.reserve_for_executor_on_rescheduled_node(
+                        pod, node
+                    )
+                except ReservationError as exc:
+                    msg = f"failed to reserve node for rescheduled executor: {exc}"
+                    finish(i, None, FAILURE_INTERNAL, msg)
+                    s["result"] = ("internal", msg)
+                    # NOT app_failed: capacity exists (the solve
+                    # admitted); a serial re-attempt by a later same-app
+                    # executor would hit the same write failure, so
+                    # those fail internal below, not failure-fit.
+                    app_internal[s["key"]] = msg
+                    continue
+                rescheduled = True
+                s["result"] = ("ok", node)
+                if s["is_extra"]:
+                    self._mark_extra(True)
+                finish(
+                    i, node,
+                    SUCCESS_SCHEDULED_EXTRA_EXECUTOR
+                    if s["is_extra"]
+                    else SUCCESS_RESCHEDULED,
+                )
+            else:
+                self._demands.create_demand_for_executor(
+                    pod, s["exec_res"], zone=s["zone"]
+                )
+                s["result"] = ("fit", None)
+                if s["is_extra"]:
+                    self._mark_extra(False)
+                finish(
+                    i, None, FAILURE_FIT,
+                    "not enough capacity to reschedule the executor",
+                )
+                app_failed.add(s["key"])
+        if rescheduled:
+            # New usage on nodes the reservations did not cover: stale
+            # in-flight windows must re-solve (one bump covers the run).
+            self._capacity_epoch += 1
+
     def _reschedule_context(
         self, executor: Pod
     ) -> tuple[Optional["Resources"], Optional[str], Optional[str]]:
@@ -1928,7 +1961,7 @@ class SparkSchedulerExtender:
         return app_resources.executor_resources, zone, None
 
     def _select_executor_node(
-        self, executor: Pod, node_names: list[str]
+        self, executor: Pod, node_names: list[str], ctx=None
     ) -> tuple[Optional[str], str, str]:
         try:
             bound_node, found = self._rrm.find_already_bound_reservation_node(executor)
@@ -1957,14 +1990,23 @@ class SparkSchedulerExtender:
         except ReservationError as exc:
             return None, FAILURE_INTERNAL, f"error when checking for remaining allowed executor count: {exc}"
         if free_spots > 0:
+            from spark_scheduler_tpu.tracing import tracer
+
             is_extra = not found_unbound
-            node, outcome, msg = self._reschedule_executor(executor, node_names, is_extra)
-            if node is None:
-                return None, outcome, msg
-            try:
-                self._rrm.reserve_for_executor_on_rescheduled_node(executor, node)
-            except ReservationError as exc:
-                return None, FAILURE_INTERNAL, f"failed to reserve node for rescheduled executor: {exc}"
+            with tracer().span("executor-reschedule"):
+                node, outcome, msg = self._reschedule_executor(
+                    executor, node_names, is_extra, ctx=ctx
+                )
+                if node is None:
+                    if is_extra and outcome == FAILURE_FIT:
+                        self._mark_extra(False)
+                    return None, outcome, msg
+                try:
+                    self._rrm.reserve_for_executor_on_rescheduled_node(executor, node)
+                except ReservationError as exc:
+                    return None, FAILURE_INTERNAL, f"failed to reserve node for rescheduled executor: {exc}"
+            if is_extra:
+                self._mark_extra(True)
             # New usage on a node the reservation did not already cover:
             # stale in-flight windows must re-solve.
             self._capacity_epoch += 1
@@ -1972,15 +2014,20 @@ class SparkSchedulerExtender:
 
         return None, FAILURE_UNBOUND, "application has no free executor spots to schedule this one"
 
+    def _mark_extra(self, placed: bool) -> None:
+        if self._metrics is not None:
+            self._metrics.mark_extra_executor(placed)
+
     def _reschedule_executor(
-        self, executor: Pod, node_names: list[str], is_extra: bool
+        self, executor: Pod, node_names: list[str], is_extra: bool, ctx=None
     ) -> tuple[Optional[str], str, str]:
         """First executor-priority-ordered node with room (resource.go:565-639),
         optionally restricted to the app's common AZ for single-AZ dynamic
         allocation. Context derivation (driver lookup, resources, single-AZ
         zone — incl. the reference's error-the-request semantics,
         resource.go:583-586) is shared with the windowed path via
-        _reschedule_context so the two ladders cannot drift."""
+        _reschedule_context so the two ladders cannot drift.
+        `ctx["solve_ms"]` gets the solve's wall time."""
         exec_res, single_az_zone, ctx_error = self._reschedule_context(
             executor
         )
@@ -1998,6 +2045,7 @@ class SparkSchedulerExtender:
         tensors = self._build_serving_tensors(self.features.snapshot())
         domain = self._solver.candidate_mask(tensors, [n.name for n in nodes])
         # A 1-executor gang with no driver = "first sorted node with room".
+        t0 = time.perf_counter()
         packing = self._solver.pack(
             "tightly-pack",
             tensors,
@@ -2007,6 +2055,8 @@ class SparkSchedulerExtender:
             [n.name for n in nodes],
             domain_mask=domain,
         )
+        if ctx is not None:
+            ctx["solve_ms"] = (time.perf_counter() - t0) * 1e3
         if packing.has_capacity and packing.executor_nodes:
             outcome = SUCCESS_SCHEDULED_EXTRA_EXECUTOR if is_extra else SUCCESS_RESCHEDULED
             return packing.executor_nodes[0], outcome, ""

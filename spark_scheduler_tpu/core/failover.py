@@ -181,10 +181,13 @@ class FailoverReconciler:
             rr = self._rr_cache.get(exec0.namespace, sp.app_id)
             if rr is None:
                 return []
-            new_rr = self._patch_resource_reservation(sp.inconsistent_executors, rr.copy())
+            new_rr, patched = self._patch_resource_reservation(
+                sp.inconsistent_executors, rr.copy()
+            )
             if new_rr is None:
                 return []
-            self._summary["patched"] = self._summary.get("patched", 0) + 1
+            if patched:
+                self._summary["patched"] = self._summary.get("patched", 0) + 1
             claimed = set(new_rr.status.pods.values())
             return [e for e in sp.inconsistent_executors if e.name not in claimed]
 
@@ -240,7 +243,15 @@ class FailoverReconciler:
 
     def _patch_resource_reservation(self, execs: list[Pod], rr):
         """Re-claim reservation slots on each executor's node when the slot
-        is unclaimed or its pod is gone/dead (failover.go:316-336)."""
+        is unclaimed or its pod is gone/dead (failover.go:316-336). Returns
+        (reservation, whether it was written), (None, False) on a failed
+        write. Documented deviation: a pass that re-claims nothing writes
+        nothing — the reference writes the unchanged reservation back. At
+        a restart every running dynamic-allocation app whose extras hold
+        no hard slot takes this path, and each no-op write is a round trip
+        to the apiserver (and, before the write-back workers start, a slot
+        of their bounded queue)."""
+        patched = False
         for e in execs:
             for name, reservation in rr.spec.reservations.items():
                 if reservation.node != e.node_name:
@@ -248,14 +259,16 @@ class FailoverReconciler:
                 current = rr.status.pods.get(name)
                 if current is None:
                     rr.status.pods[name] = e.name
+                    patched = True
                     break
                 pod = self._backend.get("pods", e.namespace, current)
                 if pod is None or pod.is_terminated():
                     rr.status.pods[name] = e.name
+                    patched = True
                     break
-        if not self._rr_cache.update(rr):
-            return None
-        return rr
+        if patched and not self._rr_cache.update(rr):
+            return None, False
+        return rr, patched
 
     # ------------------------------------------------------------- demands
 

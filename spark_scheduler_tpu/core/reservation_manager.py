@@ -344,16 +344,21 @@ class ResourceReservationManager:
                 self._bind_executor_to_resource_reservation(executor, res_name, node)
                 return
             if self._get_free_soft_reservation_spots(app_id, executor.namespace) > 0:
-                self._bind_executor_to_soft_reservation(executor, node)
+                from spark_scheduler_tpu.tracing import tracer
+
+                with tracer().span("soft-reserve"):
+                    self._bind_executor_to_soft_reservation(executor, node)
                 return
         raise ReservationError("failed to find free reservation for executor")
 
     # -- compaction ---------------------------------------------------------
 
-    def compact_dynamic_allocation_applications(self) -> None:
+    def compact_dynamic_allocation_applications(self) -> Optional[int]:
         """Migrate soft reservations of live executors into freed hard slots
         (resourcereservations.go:238-268). Apps are queued by the executor
         pod-deletion handler and drained here, on the request path.
+        Returns the hard slots taken over, or None when no app was queued
+        (the common case, which costs one lock).
 
         One unbound-slot derivation and ONE reservation write per app: the
         per-pod form re-derived the active pod set and re-wrote the
@@ -361,14 +366,20 @@ class ResourceReservationManager:
         compaction pass, a measured host cost at high dynamic-allocation
         churn. Slot choice per pod is unchanged (prefer a slot already on
         the pod's node, else the first unbound slot,
-        resourcereservations.go:283-301); a consumed slot is not re-offered
-        within the pass even when the bind leaves it node-mismatched —
-        semantically equivalent, the same deviation contract as
-        executor_ladder_batch (any unbound slot satisfies the reservation;
-        the reference itself picks arbitrarily)."""
+        resourcereservations.go:283-301), and the slot moves to the pod's
+        node, as the reference's bind does; a consumed slot is not
+        re-offered within the pass — semantically equivalent, the same
+        deviation contract as executor_ladder_batch (any unbound slot
+        satisfies the reservation; the reference itself picks
+        arbitrarily)."""
+        from spark_scheduler_tpu.tracing import tracer
+
         with self._compaction_lock:
             drained, self._compaction_apps = self._compaction_apps, {}
-        with self._mutex:
+        if not drained:
+            return None
+        moved = 0
+        with tracer().span("compact", apps=len(drained)), self._mutex:
             for app_id, namespace in drained.items():
                 sr, ok = self.soft_store.get_soft_reservation(app_id)
                 if not ok:
@@ -379,23 +390,25 @@ class ResourceReservationManager:
                 ]
                 if not live:
                     continue
-                self._compact_app(app_id, live, pods)
+                moved += self._compact_app(app_id, live, pods)
+        return moved
 
     def _compact_app(
         self, app_id: str, pods: list[Pod], active: dict[str, Pod]
-    ) -> None:
+    ) -> int:
         """`active` is the app's already-derived active-pod map — the
-        caller pays that walk exactly once per compacted app."""
+        caller pays that walk exactly once per compacted app. Returns the
+        slots taken over."""
         if not pods:
-            return
+            return 0
         namespace = pods[0].namespace
         rr = self.get_resource_reservation(app_id, namespace)
         if rr is None:
-            return
+            return 0
         unbound = self._unbound_of(rr, active)
         if not unbound:
-            return
-        binds: list[tuple[Pod, str, str]] = []  # (pod, slot, node)
+            return 0
+        binds: list[tuple[Pod, str]] = []  # (pod, slot)
         for pod in pods:
             if not unbound:
                 break
@@ -409,17 +422,17 @@ class ResourceReservationManager:
             )
             if res_name is None:
                 res_name = next(iter(unbound))
-            binds.append((pod, res_name, unbound.pop(res_name)))
-        if not binds:
-            return
+            del unbound[res_name]
+            binds.append((pod, res_name))
         updated = rr.copy()
-        for pod, res_name, node in binds:
-            updated.spec.reservations[res_name].node = node
+        for pod, res_name in binds:
+            updated.spec.reservations[res_name].node = pod.node_name
             updated.status.pods[res_name] = pod.name
         if not self.rr_cache.update(updated):
             raise ReservationError("failed to update resource reservation")
-        for pod, _res_name, _node in binds:
+        for pod, _res_name in binds:
             self.soft_store.remove_executor_reservation(app_id, pod.name)
+        return len(binds)
 
     # -- internals ----------------------------------------------------------
 

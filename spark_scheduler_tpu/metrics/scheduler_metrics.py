@@ -27,6 +27,9 @@ SINGLE_AZ_PACK_FAILURE = (
     "foundry.spark.scheduler.singleazdynamicallocationpackfailure.count"
 )
 COMPACTION_TIME = "foundry.spark.scheduler.softreservation.compaction.time"
+COMPACTION_SLOTS = "foundry.spark.scheduler.softreservation.compaction.slots"
+EXTRA_PLACED = "foundry.spark.scheduler.softreservation.extra.placed"
+EXTRA_FAILED = "foundry.spark.scheduler.softreservation.extra.failed"
 
 TAG_ROLE = "sparkrole"
 TAG_OUTCOME = "outcome"
@@ -104,8 +107,16 @@ class SchedulerMetrics:
             RECONCILIATION_TIME, **{TAG_INSTANCE_GROUP: instance_group}
         ).update(elapsed_s)
 
-    def mark_compaction(self, elapsed_s: float):
+    def mark_compaction(self, elapsed_s: float, slots: int):
+        """One compaction pass that drained at least one app: its time, and
+        the hard slots soft-reserved executors took over in it."""
         self.registry.histogram(COMPACTION_TIME).update(elapsed_s)
+        self.registry.counter(COMPACTION_SLOTS).inc(slots)
+
+    def mark_extra_executor(self, placed: bool):
+        """An executor beyond its app's minimum: placed on a soft
+        reservation, or denied for want of room."""
+        self.registry.counter(EXTRA_PLACED if placed else EXTRA_FAILED).inc()
 
     def mark_single_az_dynamic_allocation_pack_failure(self, zone: str):
         self.registry.counter(SINGLE_AZ_PACK_FAILURE, zone=zone).inc()
