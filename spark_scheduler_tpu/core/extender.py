@@ -2043,23 +2043,16 @@ class SparkSchedulerExtender:
             nodes = [n for n in nodes if n.zone == single_az_zone]
 
         tensors = self._build_serving_tensors(self.features.snapshot())
-        domain = self._solver.candidate_mask(tensors, [n.name for n in nodes])
         # A 1-executor gang with no driver = "first sorted node with room".
         t0 = time.perf_counter()
-        packing = self._solver.pack(
-            "tightly-pack",
-            tensors,
-            type(exec_res).zero(),
-            exec_res,
-            1,
-            [n.name for n in nodes],
-            domain_mask=domain,
+        node = self._solver.first_fit_executor(
+            tensors, exec_res, [n.name for n in nodes]
         )
         if ctx is not None:
             ctx["solve_ms"] = (time.perf_counter() - t0) * 1e3
-        if packing.has_capacity and packing.executor_nodes:
+        if node is not None:
             outcome = SUCCESS_SCHEDULED_EXTRA_EXECUTOR if is_extra else SUCCESS_RESCHEDULED
-            return packing.executor_nodes[0], outcome, ""
+            return node, outcome, ""
 
         self._demands.create_demand_for_executor(
             executor, exec_res, zone=single_az_zone

@@ -43,8 +43,16 @@ from spark_scheduler_tpu.models.cluster import (
     cluster_statics,
 )
 from spark_scheduler_tpu.models.kube import Node
-from spark_scheduler_tpu.models.resources import INT32_INF, NUM_DIMS, Resources
+from spark_scheduler_tpu.models.resources import (
+    CPU_DIM,
+    INT32_INF,
+    MEM_DIM,
+    NUM_DIMS,
+    Resources,
+)
 from spark_scheduler_tpu.ops import BINPACK_FUNCTIONS
+from spark_scheduler_tpu.ops.capacity import node_capacities
+from spark_scheduler_tpu.ops.sorting import zone_ranks
 from spark_scheduler_tpu.ops.batched import batched_fifo_pack, make_app_batch
 from spark_scheduler_tpu.ops.efficiency import avg_packing_efficiency_np
 
@@ -814,6 +822,43 @@ def _pack_blob(cluster, dreq, ereq, count, dmask, dom, *, fill, emax, num_zones)
     )
 
 
+@_partial(jax.jit, static_argnames=("num_zones",))
+def _first_fit_executor_blob(cluster, ereq, cand, *, num_zones):
+    """The row a lone executor takes under tightly-pack, as one int32 (-1:
+    no eligible row has room). Eligible rows are `cand & valid &
+    ~unschedulable & ready`; of those with room for one executor, the one
+    with the least executor-priority key (label rank, zone rank, available
+    memory, available CPU, name rank, row), found by one masked
+    min-reduction per key instead of sorting.
+
+    Equal to `_pack_blob(zero driver, count=1, "tightly-pack")` with `cand`
+    as both masks, on every input. Its executor slot 0 is the first
+    position of `priority_order`'s stable lexsort (the same keys, ties to
+    the lower row) whose capacity is >= 1: this row. Its `has_capacity` is
+    `found & fill_ok`, where fill_ok is "some eligible row has capacity
+    >= 1". A zero driver leaves every capacity unchanged, so found is "some
+    candidate row fits a zero request, and the rows' capacities sum to
+    >= 1"; that sum is fill_ok, and a row with capacity >= 1 is a candidate
+    row with no dimension below zero (node_capacities reads 0 for such a
+    dimension), so it fits a zero request. Hence has_capacity == fill_ok
+    == (row >= 0)."""
+    avail = cluster.available
+    elig = cand & cluster.valid & ~cluster.unschedulable & cluster.ready
+    live = elig & (node_capacities(avail, jnp.zeros_like(avail), ereq) >= 1)
+    zrank = zone_ranks(cluster, cand, num_zones)
+    for key in (
+        cluster.label_rank_executor,
+        zrank[cluster.zone_id],
+        avail[:, MEM_DIM],
+        avail[:, CPU_DIM],
+        cluster.name_rank,
+    ):
+        live = live & (key == jnp.min(jnp.where(live, key, INT32_INF)))
+    # Ties on every key go to the lower row, as in the stable lexsort.
+    first = jnp.argmax(live).astype(jnp.int32)
+    return jnp.where(live[first], first, -1)
+
+
 class _NameRankSpace:
     """Order-maintenance name ranks for the native arena (the node-ADD
     cold-rebuild fix, ISSUE 11).
@@ -1388,6 +1433,10 @@ class PlacementSolver:
         # object is what lets the prune planner's per-domain contexts
         # recognize an unchanged domain across windows (ISSUE 15).
         self._dom_and_memo: LRUCache = LRUCache(32)
+        # Candidate mask -> its device copy, identity-keyed with the host
+        # mask pinned alive: while candidate_mask returns the same object,
+        # a lone executor's first fit ships no mask.
+        self._dev_mask_memo: LRUCache = LRUCache(8)
         # Per-names patch bases for the epoch-journal candidate-mask
         # patch (ISSUE 13): names-key -> (epoch, n, mask, unresolved
         # names, removed member names) — see _cand_try_patch.
@@ -2152,6 +2201,7 @@ class PlacementSolver:
         self._snap_res = None  # resident host buffers
         self._avail_undo.clear()
         self._prune_gather_cache.clear()  # release cached device statics
+        self._dev_mask_memo.clear()
         self._release_fused()
         self._release_pool()
 
@@ -3501,65 +3551,114 @@ class PlacementSolver:
         driver_candidate_names: Sequence[str],
         domain_mask: np.ndarray | None = None,
     ) -> HostPacking:
-        from spark_scheduler_tpu.tracing import tracer
-
         n = tensors.available.shape[0]
         host = _host_view(tensors)
         driver_mask = self.candidate_mask(tensors, driver_candidate_names)
         if domain_mask is None:
             domain_mask = np.asarray(host.valid)
         emax = _bucket(max(executor_count, 1), 8)
+
+        def device():
+            # ONE device->host transfer (one flat int32 blob) for the whole
+            # decision: every fetched array is a full device round trip
+            # (SURVEY.md §7 latency budget). Efficiency reporting runs as
+            # pure numpy on the host-resident cluster arrays — zero extra
+            # pulls.
+            return _shimmed_device_get(
+                _pack_blob(
+                    tensors,
+                    jnp.asarray(driver_resources.as_array()),
+                    jnp.asarray(executor_resources.as_array()),
+                    jnp.int32(executor_count),
+                    jnp.asarray(driver_mask),
+                    jnp.asarray(domain_mask),
+                    fill=strategy,
+                    emax=emax,
+                    num_zones=self._num_zones_bucket(),
+                )
+            )
+
+        def finish(blob) -> HostPacking:
+            if self.telemetry is not None:
+                self.telemetry.on_pack(nodes=n, emax=emax)
+                self.telemetry.on_transfer("d2h", getattr(blob, "nbytes", 0))
+            driver_idx = int(blob[0])
+            has_cap = bool(blob[1])
+            executor_nodes = blob[2:]
+            eff = avg_packing_efficiency_np(
+                np.asarray(host.schedulable),
+                np.asarray(host.available),
+                driver_idx,
+                executor_nodes,
+                driver_resources.as_array(),
+                executor_resources.as_array(),
+            )
+            exec_idx = [int(x) for x in executor_nodes if int(x) >= 0]
+            return HostPacking(
+                driver_node=(
+                    self.registry.name_of(driver_idx) if driver_idx >= 0 else None
+                ),
+                executor_nodes=[self.registry.name_of(i) for i in exec_idx],
+                has_capacity=has_cap,
+                efficiency_max=float(eff.max),
+                efficiency_cpu=float(eff.cpu),
+                efficiency_memory=float(eff.memory),
+                efficiency_gpu=float(eff.gpu),
+            )
+
+        return self._solo_solve(
+            strategy=strategy,
+            nodes=n,
+            executors=executor_count,
+            emax=emax,
+            path="xla",
+            device=device,
+            finish=finish,
+            fallback=lambda: self.fallback.pack(
+                strategy, host, driver_resources, executor_resources,
+                executor_count, driver_mask, domain_mask,
+            ),
+        )
+
+    def _solo_solve(
+        self, *, strategy, nodes, executors, emax, path, device, finish, fallback
+    ):
+        """One solo decision (`pack`, `first_fit_executor`): `device()`
+        dispatches and fetches inside the `solve` span, whose end is where
+        the device work is awaited, and `finish(fetched)` turns the fetch
+        into the answer. A slot failure that classify_slot_failure accepts
+        serves just this decision from `fallback()`: a solo solve does not
+        thread the pipelined base, so the pipeline survives. Anything else
+        propagates."""
+        from spark_scheduler_tpu.tracing import tracer
+
         tel = self.telemetry
         compiles_before = tel.compile_count() if tel is not None else None
-        # The span covers dispatch AND the device->host transfer — the
-        # transfer is where the device work is actually awaited.
         try:
             with tracer().span(
-                "solve", strategy=strategy, nodes=n, executors=executor_count
+                "solve", strategy=strategy, nodes=nodes, executors=executors
             ):
-                # ONE device->host transfer (one flat int32 blob) for the whole
-                # decision: every fetched array is a full device round trip
-                # (SURVEY.md §7 latency budget). Efficiency reporting
-                # runs as pure numpy on the host-resident cluster arrays — zero
-                # extra pulls.
                 _shim("h2d")
-                blob = _shimmed_device_get(
-                    _pack_blob(
-                        tensors,
-                        jnp.asarray(driver_resources.as_array()),
-                        jnp.asarray(executor_resources.as_array()),
-                        jnp.int32(executor_count),
-                        jnp.asarray(driver_mask),
-                        jnp.asarray(domain_mask),
-                        fill=strategy,
-                        emax=emax,
-                        num_zones=self._num_zones_bucket(),
-                    )
-                )
+                fetched = device()
         except Exception as exc:
             if not (
                 classify_slot_failure(exc) and self.degraded is not None
             ):
                 raise
-            # Solo pack does not thread the pipelined base, so the
-            # pipeline survives; just this decision serves degraded.
             self._degraded_or_raise(exc)
             self.last_solve_info = {
                 "path": "greedy-fallback",
-                "nodes": n,
+                "nodes": nodes,
                 "emax": emax,
                 "compile_cache_hit": None,
                 "degraded": True,
             }
-            packing = self.fallback.pack(
-                strategy, host, driver_resources, executor_resources,
-                executor_count, driver_mask, domain_mask,
-            )
+            answer = fallback()
             self.degraded.on_fallback_decision()
-            return packing
+            return answer
         self.last_solve_info = {
-            "path": "xla",
-            "nodes": n,
+            "path": path,
+            "nodes": nodes,
             "emax": emax,
             "compile_cache_hit": (
                 tel.compile_count() == compiles_before
@@ -3567,30 +3666,67 @@ class PlacementSolver:
                 else None
             ),
         }
-        if tel is not None:
-            tel.on_pack(nodes=n, emax=emax)
-            tel.on_transfer("d2h", getattr(blob, "nbytes", 0))
-        driver_idx = int(blob[0])
-        has_cap = bool(blob[1])
-        executor_nodes = blob[2:]
-        eff = avg_packing_efficiency_np(
-            np.asarray(host.schedulable),
-            np.asarray(host.available),
-            driver_idx,
-            executor_nodes,
-            driver_resources.as_array(),
-            executor_resources.as_array(),
-        )
-        exec_idx = [int(x) for x in executor_nodes if int(x) >= 0]
+        answer = finish(fetched)
         self._device_recovered()
-        return HostPacking(
-            driver_node=self.registry.name_of(driver_idx) if driver_idx >= 0 else None,
-            executor_nodes=[self.registry.name_of(i) for i in exec_idx],
-            has_capacity=has_cap,
-            efficiency_max=float(eff.max),
-            efficiency_cpu=float(eff.cpu),
-            efficiency_memory=float(eff.memory),
-            efficiency_gpu=float(eff.gpu),
+        return answer
+
+    def _device_mask(self, mask: np.ndarray):
+        """`mask` on the device, kept across calls (see `_dev_mask_memo`)."""
+        hit = self._dev_mask_memo.get(id(mask))
+        if hit is not None and hit[0] is mask:
+            return hit[1]
+        dev = jax.device_put(mask)
+        self._dev_mask_memo.put(id(mask), (mask, dev))
+        return dev
+
+    def first_fit_executor(
+        self,
+        tensors,
+        executor_resources: Resources,
+        candidate_names: Sequence[str],
+    ) -> Optional[str]:
+        """The node a lone executor (a 1-executor gang with no driver)
+        takes: the first candidate in tightly-pack's executor order with
+        room for one executor, or None when none has room. The same answer
+        as `pack("tightly-pack", tensors, zero, executor_resources, 1,
+        candidate_names, domain_mask=<the candidates' mask>)`, from
+        `_first_fit_executor_blob`: no sort, the candidate mask kept on
+        the device, one int32 fetched."""
+        n = tensors.available.shape[0]
+        cand = self.candidate_mask(tensors, candidate_names)
+
+        def finish(row) -> Optional[str]:
+            if self.telemetry is not None:
+                self.telemetry.on_executor_first_fit(nodes=n)
+                self.telemetry.on_transfer("d2h", 4)
+            row = int(row)
+            return self.registry.name_of(row) if row >= 0 else None
+
+        def fallback() -> Optional[str]:
+            packing = self.fallback.pack(
+                "tightly-pack", _host_view(tensors), Resources.zero(),
+                executor_resources, 1, cand, cand,
+            )
+            if packing.has_capacity and packing.executor_nodes:
+                return packing.executor_nodes[0]
+            return None
+
+        return self._solo_solve(
+            strategy="tightly-pack",
+            nodes=n,
+            executors=1,
+            emax=1,
+            path="xla-first-fit",
+            device=lambda: _shimmed_device_get(
+                _first_fit_executor_blob(
+                    tensors,
+                    executor_resources.as_array(),
+                    self._device_mask(cand),
+                    num_zones=self._num_zones_bucket(),
+                )
+            ),
+            finish=finish,
+            fallback=fallback,
         )
 
     def can_batch(self, strategy: str) -> bool:

@@ -27,6 +27,8 @@ BUCKET_OCCUPANCY = "foundry.spark.scheduler.solver.bucket.occupancy"
 PIPELINE_EVENTS = "foundry.spark.scheduler.solver.pipeline.events"
 TRANSFER_BYTES = "foundry.spark.scheduler.solver.transfer.bytes"
 SOLO_PACKS = "foundry.spark.scheduler.solver.packs"
+# Lone extra executors placed by the single-executor first-fit program.
+EXECUTOR_FIRST_FITS = "foundry.spark.scheduler.solver.executor_first_fits"
 # Multi-device window-solve engine (core/solver.py _DevicePool): per-slot
 # series tagged device=<label>.
 DEVICE_UPLOADS = "foundry.spark.scheduler.solver.device.uploads"
@@ -261,6 +263,9 @@ class SolverTelemetry:
         self.registry.counter(
             SOLO_PACKS, nodes=str(nodes), emax=str(emax)
         ).inc()
+
+    def on_executor_first_fit(self, *, nodes: int) -> None:
+        self.registry.counter(EXECUTOR_FIRST_FITS, nodes=str(nodes)).inc()
 
     # -- fused dispatch ------------------------------------------------------
 

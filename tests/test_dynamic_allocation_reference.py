@@ -556,6 +556,50 @@ def test_a_window_of_extra_executors_is_one_spanned_reschedule_pass():
         run.served.app.stop()
 
 
+def test_a_serial_extra_executor_takes_the_first_fit_program_not_the_gang_pack():
+    """A lone extra executor is placed by the single-executor first-fit
+    program: `executor_first_fits` moves by one, `solver.packs` not at
+    all, and the executor's record still carries the solve's time. A window
+    of two extras still takes the grouped pass through pack_window, which
+    moves neither."""
+    first_fits = "foundry.spark.scheduler.solver.executor_first_fits"
+    packs = "foundry.spark.scheduler.solver.packs"
+
+    def counts():
+        snap = run.served.registry.snapshot()
+        return tuple(sum(e["value"] for e in snap.get(k, [])) for k in (first_fits, packs))
+
+    run = Run(29, 24, 2, 8)
+    try:
+        run.arrive("app-f")
+        assert run.driver("app-f")
+        run.executors("app-f", 2)  # the hard slots: no solve
+        before = counts()
+        run.executors("app-f", 1)
+        assert run.outcomes[-1] == SUCCESS_SCHEDULED_EXTRA_EXECUTOR
+        assert counts() == (before[0] + 1, before[1])
+        (rec,) = run.served.app.recorder.query(role="executor", limit=1)
+        assert "solve_ms" in rec["phases"]
+
+        solver = run.served.app.solver
+        windows = sum(solver.window_path_counts.values())
+        pods = [pod("app-f", ROLE_EXECUTOR, k=k) for k in (4, 5)]
+        want = []
+        for p in pods:
+            run.served.backend.add_pod(p)
+            rung, node = run.ref.executor_request("app-f")
+            assert rung == "extra"
+            run.ref.place("app-f", p.name, node)
+            want.append(run.names[node])
+        res = run.served.app.extender.predicate_batch(
+            [ExtenderArgs(pod=p, node_names=run.names) for p in pods])
+        assert [r.node_names for r in res] == [[n] for n in want]
+        assert counts() == (before[0] + 1, before[1])
+        assert sum(solver.window_path_counts.values()) == windows + 1
+    finally:
+        run.served.app.stop()
+
+
 def test_restart_rebuilds_soft_reservations_without_rewriting_reservations():
     """A restart over 1,200 running dynamic-allocation apps whose hard slots
     are all held and whose extra executor is bound with no reservation: the
