@@ -16,8 +16,7 @@ groups, pipelined serving windows through the extender):
               byte-identical).
 
 Forces an 8-device virtual CPU mesh BEFORE jax initializes, so it runs
-as a subprocess (bench.py `fault_recovery` section). One JSON line per
-arm on stdout; standalone:
+in a process of its own. One JSON line per arm on stdout:
     python hack/fault_recovery_bench.py
 """
 

@@ -4,8 +4,8 @@ The fused engine's win is invisible on a local CPU backend (device
 boundaries are microseconds), so this bench injects a device round trip
 with the simulated-RTT device shim (testing/rtt_shim.py): every window
 DISPATCH pays rtt/2 on the dispatcher thread and every decision pull pays
-rtt/2 on a fetch thread — the structure bench.py reports as
-`device_rtt_floor_ms` (not measured on the v5e chip yet; PERF.md).
+rtt/2 on a fetch thread (the real round trip is not measured on the v5e
+chip yet; PERF.md).
 
 Arms: fused_k in {1, 4} (1 = today's one-window-per-dispatch serving
 loop, pipelined dispatch-before-fetch; 4 = the fused claim — 4 windows
@@ -16,9 +16,9 @@ REAL extender dispatch/complete path (reservations, write-back, epoch
 machinery) — the HTTP layer is out of frame, as in the in-process
 controls of every serving section.
 
-Runs as a subprocess of bench.py's `fused_dispatch` section (the pool
-arms need the 8-device virtual CPU mesh forced before jax initializes).
-One JSON line per arm on stdout; standalone:
+The pool arms need the 8-device virtual CPU mesh forced before jax
+initializes, so it runs in a process of its own. One JSON line per arm on
+stdout:
     python hack/fused_dispatch_bench.py
 """
 
@@ -49,9 +49,8 @@ NODES_PER_GROUP = 128
 WINDOW = 8  # requests per serving window
 N_WINDOWS = 8  # measured windows per arm
 EXECS = 2
-# (pool, fused_k, rtt_ms): the single-device RTT sweep is the
-# PERFORMANCE.md table; the pool pair shows fusion composing with the
-# multi-device engine.
+# (pool, fused_k, rtt_ms): the single-device RTT sweep, and a pool pair
+# showing fusion composing with the multi-device engine.
 ARMS = (
     (1, 1, 10), (1, 4, 10),
     (1, 1, 50), (1, 4, 50), (1, 8, 50),

@@ -1,4 +1,4 @@
-"""HA sharded-serving + chaos arms of bench.py's ha_failover section.
+"""HA sharded-serving + chaos arms: replica failover under sharding.
 
 Measures 2-active-replica instance-group sharding (ha/replica.py
 ShardedServingGroup) against a single unsharded replica on the SAME
@@ -10,7 +10,7 @@ carries the >= 1.5x bar). Byte-identical per-group placements are
 ASSERTED in both arms. Then runs the leader-kill chaos soak
 (testing/soak.py HAChaosSoak, >= 3 cycles).
 
-Runs as a SUBPROCESS of bench.py (like hack/multidevice_bench.py). The
+Runs in a process of its own (like hack/multidevice_bench.py). The
 persistent XLA compilation cache is ENABLED again: the historical flake
 (concurrently-serving solvers intermittently produced wrong window
 decisions on executables reloaded from the cache, so this arm used to
@@ -18,7 +18,7 @@ run cache-free) is closed by InstallConfig.serialize_jax_cache_io() —
 the cache's executable serialize/deserialize + file I/O now runs behind
 one process-wide lock, which enable_jax_compile_cache installs. The
 equivalence assertions below are the regression guard: a recurrence
-fails the arm loudly. One JSON line per arm on stdout; standalone:
+fails the arm loudly. One JSON line per arm on stdout:
     python hack/ha_shard_bench.py
 """
 

@@ -5,7 +5,7 @@ pull. This script runs the SAME serving path — predicate_batch -> window
 solve -> reservation write-back — entirely in process against the CPU
 backend, so the per-call cost is the solve and host cycle itself.
 
-Run by bench.py as a subprocess (one JSON line on stdout); standalone:
+One JSON line on stdout:
     python hack/inprocess_bench.py
 """
 

@@ -13,9 +13,8 @@ sub-solves. One arm per device-pool size:
              pool, the committed base scatter-combined between windows.
 
 Forces an 8-device virtual CPU mesh BEFORE jax initializes, so it must run
-as a subprocess (bench.py `multi_device_serving` section) — the parent
-process's jax is already bound to its backend. One JSON line per arm on
-stdout; standalone:
+in a process of its own, not one whose jax is already bound to its
+backend. One JSON line per arm on stdout:
     python hack/multidevice_bench.py
 """
 

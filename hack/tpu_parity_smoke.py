@@ -184,90 +184,20 @@ def run(require_tpu: bool = False) -> dict:
                         base[e] -= np.asarray(seg["rows"][-1][1])
         checked += 1
 
-    # -- Pallas queue kernel on silicon: the Mosaic program must equal the
-    #    XLA scan decision-for-decision (same comparison as
-    #    tests/test_pallas_fifo.py, here COMPILED on the real backend).
-    from spark_scheduler_tpu.ops.pallas_fifo import (
+    # -- Pallas SEGMENTED WINDOW kernel on silicon: the scan-over-segments
+    #    Mosaic program must equal the segmented XLA scan
+    #    decision-for-decision for all six strategies (plain fills, and the
+    #    single-AZ wrappers through make_gang_solver).
+    from spark_scheduler_tpu.ops.pallas_window import (
         PALLAS_FILLS,
-        fifo_pack_pallas,
+        PALLAS_SINGLE_AZ,
         pallas_available,
+        window_pack_pallas,
     )
 
     if pallas_available():
-        from spark_scheduler_tpu.ops.pallas_fifo import _SUBLANE_FOLD_MIN_NODES
-
-        # Every fill at the small size (flat [1, Np] layout) AND above the
-        # sublane-fold threshold (the [8, cols] layout): both compiled
-        # layouts of all three fills are parity-checked on silicon.
-        cases = [(fill, N_NODES) for fill in PALLAS_FILLS] + [
-            (fill, _SUBLANE_FOLD_MIN_NODES + 104) for fill in PALLAS_FILLS
-        ]
-        for fill, n_case in cases:
-            c = TG.random_cluster(rng, n_case)
-            b = 8
-            drivers = rng.integers(1, 6, size=(b, 3)).astype(np.int32)
-            execs = rng.integers(1, 8, size=(b, 3)).astype(np.int32)
-            counts = rng.integers(0, emax + 3, size=b).astype(np.int32)
-            apps = make_app_batch(
-                drivers, execs, counts,
-                skippable=rng.random(b) < 0.5,
-            )
-            want = jax.device_get(
-                batched_fifo_pack(c, apps, fill=fill, emax=emax,
-                                  num_zones=num_zones)
-            )
-            got = jax.device_get(
-                fifo_pack_pallas(c, apps, fill=fill, emax=emax,
-                                 num_zones=num_zones)
-            )
-            for field in ("driver_node", "executor_nodes", "admitted",
-                          "packed", "available_after"):
-                assert np.array_equal(
-                    np.asarray(getattr(got, field)),
-                    np.asarray(getattr(want, field)),
-                ), ("pallas", fill, field, device)
-            checked += 1
-
-    # -- Pallas single-AZ strategies on silicon (VERDICT r3 #4): per-zone
-    #    pack + efficiency-scored zone pick in-kernel == the XLA scan.
-    if pallas_available():
-        from spark_scheduler_tpu.ops.pallas_fifo import PALLAS_SINGLE_AZ
-
-        for saz_fill in sorted(PALLAS_SINGLE_AZ):
-            srng = np.random.default_rng(151 + len(saz_fill))
-            c = TG.random_cluster(srng, N_NODES)
-            b = 8
-            apps = make_app_batch(
-                srng.integers(1, 6, size=(b, 3)).astype(np.int32),
-                srng.integers(1, 8, size=(b, 3)).astype(np.int32),
-                srng.integers(0, emax + 3, size=b).astype(np.int32),
-                skippable=srng.random(b) < 0.5,
-            )
-            want = jax.device_get(
-                batched_fifo_pack(c, apps, fill=saz_fill, emax=emax,
-                                  num_zones=num_zones)
-            )
-            got = jax.device_get(
-                fifo_pack_pallas(c, apps, fill=saz_fill, emax=emax,
-                                 num_zones=num_zones)
-            )
-            for field in ("driver_node", "executor_nodes", "admitted",
-                          "packed", "available_after"):
-                assert np.array_equal(
-                    np.asarray(getattr(got, field)),
-                    np.asarray(getattr(want, field)),
-                ), ("pallas-single-az", saz_fill, field, device)
-            checked += 1
-
-    # -- Pallas SEGMENTED WINDOW path on silicon (VERDICT r3 #3): the
-    #    scan-over-segments Mosaic program must equal the segmented XLA
-    #    scan decision-for-decision for all six strategies (plain fills,
-    #    and since r5 the single-AZ wrappers through make_gang_solver).
-    if pallas_available():
         from tests.test_pallas_window import _cluster as _pw_cluster
         from tests.test_pallas_window import _random_window as _pw_window
-        from spark_scheduler_tpu.ops.pallas_fifo import PALLAS_SINGLE_AZ
-        from spark_scheduler_tpu.ops.pallas_window import window_pack_pallas
 
         for fill in PALLAS_FILLS + tuple(PALLAS_SINGLE_AZ):
             prng = np.random.default_rng(97 + len(fill))
@@ -297,54 +227,6 @@ def run(require_tpu: bool = False) -> dict:
                 np.asarray(base_after), np.asarray(want.available_after)
             ), ("pallas-window", fill, "base", device)
             checked += 1
-
-    # -- grouped single-chip fast path: the jitted per-group Pallas loop
-    #    (grouped_fifo_pack_auto) must equal the vmapped XLA scan
-    #    group-for-group on silicon.
-    if pallas_available():
-        from spark_scheduler_tpu.parallel import (
-            grouped_fifo_pack,
-            grouped_fifo_pack_auto,
-            make_solver_mesh,
-            stack_groups,
-        )
-
-        # One-device mesh EXPLICITLY: on a multi-chip host a full-device
-        # mesh would route auto to the GSPMD scan and this check would
-        # vacuously compare the scan with itself.
-        mesh = make_solver_mesh(n_groups=1, devices=jax.devices()[:1])
-        clusters, app_batches = [], []
-        for _ in range(3):
-            clusters.append(TG.random_cluster(rng, N_NODES))
-            b = 6
-            app_batches.append(
-                make_app_batch(
-                    rng.integers(1, 6, size=(b, 3)).astype(np.int32),
-                    rng.integers(1, 6, size=(b, 3)).astype(np.int32),
-                    rng.integers(0, emax + 1, size=b).astype(np.int32),
-                    skippable=rng.random(b) < 0.5,
-                )
-            )
-        sc, sa = stack_groups(clusters, app_batches)
-        want = jax.device_get(
-            grouped_fifo_pack(
-                mesh, sc, sa, fill="tightly-pack", emax=emax,
-                num_zones=num_zones,
-            )
-        )
-        got = jax.device_get(
-            grouped_fifo_pack_auto(
-                mesh, sc, sa, fill="tightly-pack", emax=emax,
-                num_zones=num_zones,
-            )
-        )
-        for field in ("driver_node", "executor_nodes", "admitted", "packed",
-                      "available_after"):
-            assert np.array_equal(
-                np.asarray(getattr(got, field)),
-                np.asarray(getattr(want, field)),
-            ), ("grouped-pallas", field, device)
-        checked += 1
 
     return {"device": device, "cases_checked": checked, "parity": "ok"}
 

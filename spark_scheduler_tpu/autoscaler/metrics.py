@@ -33,8 +33,8 @@ class AutoscalerMetrics:
         self.registry = registry or MetricRegistry()
         self._lock = threading.Lock()
         # Raw demand-to-fulfilled latencies (seconds) for exact percentile
-        # reporting in bench.py; bounded so a long-lived server can't grow
-        # it without bound.
+        # reporting; bounded so a long-lived server can't grow it without
+        # bound.
         self._scaleup_samples: list[float] = []
 
     # -- hooks ---------------------------------------------------------------

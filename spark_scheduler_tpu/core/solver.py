@@ -1985,7 +1985,7 @@ class PlacementSolver:
 
     def device_pool_stats(self) -> dict:
         """Per-slot resident-state stats ({label: {full, reuse,
-        inflight}}) — surfaced by bench.py's multi-device section."""
+        inflight}})."""
         return self._pool.stats() if self._pool is not None else {}
 
 

@@ -7,7 +7,7 @@ the excluded rows' per-zone availability sums that keep the gathered
 sub-cluster's zone ranks byte-exact (ops/sorting.zone_ranks) — by
 summing over the N−K excluded rows per window: a bincount over the whole
 roster, the measured residual behind the 1M-tier window costing ~7x the
-100k number in the same run (PERFORMANCE.md "Million-node tier").
+100k number in the same run.
 
 This module keeps the per-zone totals RESIDENT and event-maintained,
 exactly like the soft-usage mirror and the control-loop census of PR 11:

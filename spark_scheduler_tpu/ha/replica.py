@@ -9,7 +9,7 @@ A replica is a full SchedulerApp built over a FencedBackend, plus:
     reconciler against observed pods (the reference's new-leader rebuild,
     failover.go:35-72), warm the feature-store snapshot, and only then
     mark the replica serving. Warm caches make this a reconcile, not a
-    state rebuild (bench.py ha_failover measures the gap).
+    state rebuild.
 
 `ShardedServingGroup` composes N replicas over ONE shared backend into
 the active-active topology: traffic shards by instance group (ShardMap),

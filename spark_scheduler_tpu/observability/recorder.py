@@ -9,9 +9,8 @@ solve hit the XLA compile cache, and how long did each phase
 the autoscaler annotates records whose demand it later fulfilled, closing
 the denied -> demand -> scale-up -> fulfilled story on one object.
 
-Appends are O(1) under one lock (a dict build + deque append) — the
-recorder rides the serving hot path, and bench.py's recorder-overhead
-section measures, rather than assumes, that this stays in the noise.
+Appends are O(1) under one lock (a dict build + deque append): the
+recorder rides the serving hot path.
 """
 
 from __future__ import annotations

@@ -20,9 +20,6 @@ from spark_scheduler_tpu.ops.packing import (  # noqa: F401
     SINGLE_AZ_PACKERS,
 )
 from spark_scheduler_tpu.ops.capacity import node_capacities, fits  # noqa: F401
-from spark_scheduler_tpu.ops.pallas_fifo import (  # noqa: F401
-    fifo_pack_auto,
-    pallas_available,
-)
+from spark_scheduler_tpu.ops.pallas_window import pallas_available  # noqa: F401
 from spark_scheduler_tpu.ops.sorting import priority_order  # noqa: F401
 from spark_scheduler_tpu.ops.efficiency import avg_packing_efficiency  # noqa: F401

@@ -108,9 +108,7 @@ class AppBatch(NamedTuple):
 def queue_mode_orders(cluster: ClusterTensors, num_zones: int):
     """Queue-mode eligibility + priority orders, fixed from the starting
     availability (fitEarlierDrivers reuses the orders computed at
-    resource.go:299 while only availability mutates). Shared by the XLA
-    scan and the Pallas queue kernel (ops/pallas_fifo.py) so the two paths
-    cannot drift.
+    resource.go:299 while only availability mutates).
 
     Returns (driver_elig, exec_elig, d_order, d_rank, e_order, zrank)."""
     domain0 = cluster.valid

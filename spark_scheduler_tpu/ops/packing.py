@@ -544,8 +544,7 @@ def preemption_batched_fit(
     cpu/mem), so the whole per-candidate program — zone ranks, both priority
     orders, and the `pack_one_app` feasibility identity — is vmapped over
     the candidate axis and compiled once: no per-candidate Python loop over
-    kernel calls, which is what makes the search affordable at 100k nodes
-    (see PERFORMANCE.md).
+    kernel calls, which is what makes the search affordable at 100k nodes.
 
     Eligibility masks are availability-independent and computed once.
     Returns (ok[C] bool, driver_node[C] i32, exec_nodes[C,Emax] i32). With

@@ -12,7 +12,6 @@ psum/all-gather/all-to-all it needs (scaling-book recipe).
 from spark_scheduler_tpu.parallel.mesh import make_pool_slots, make_solver_mesh
 from spark_scheduler_tpu.parallel.solve import (
     grouped_fifo_pack,
-    grouped_fifo_pack_auto,
     node_sharding,
     shard_apps,
     sharded_fifo_pack,
@@ -26,6 +25,5 @@ __all__ = [
     "shard_apps",
     "sharded_fifo_pack",
     "grouped_fifo_pack",
-    "grouped_fifo_pack_auto",
     "stack_groups",
 ]

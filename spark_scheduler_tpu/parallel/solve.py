@@ -139,161 +139,6 @@ def stack_groups(
     return cluster, AppBatch(*stacked_cols)
 
 
-def _grouped_pallas_sharded(
-    mesh: Mesh,
-    clusters: ClusterTensors,  # leaves stacked [G, N, ...]
-    apps: AppBatch,  # leaves stacked [G, B, ...]
-    *,
-    fill: str,
-    emax: int,
-    num_zones: int,
-    interpret: bool = False,
-) -> BatchedPacking:
-    """The MULTI-CHIP Mosaic path (VERDICT r3 #5): instance groups are
-    independent subproblems, so shard the group axis across the mesh with
-    `shard_map` and run the Pallas queue kernel per group on each device —
-    SPMD data parallelism with ZERO cross-device collectives in the solve
-    (the scaling-book recipe: pick the axis with no data dependence).
-
-    Sharding the NODE axis of one large cluster through the kernel would
-    put a cross-shard argmin + capacity psum inside every fill round
-    (emax collectives per app, latency-bound on ICI); measured single-chip
-    Pallas at 100k nodes (16.6 ms, PERFORMANCE.md) already beats the
-    node-sharded XLA scan, so node-axis scale-out stays on the GSPMD scan
-    (`sharded_fifo_pack`) and chip scale-out happens on the group axis."""
-    from jax import shard_map
-
-    g = clusters.available.shape[0]
-    n_dev = mesh.shape["groups"]
-    if g % n_dev:
-        raise ValueError(
-            f'group count {g} not divisible by mesh "groups" axis {n_dev}'
-        )
-    g_local = g // n_dev
-
-    def body(local_c, local_a):
-        return _grouped_pallas(
-            local_c, local_a, fill=fill, emax=emax, num_zones=num_zones,
-            g=g_local, interpret=interpret,
-        )
-
-    # check_vma/check_rep: the replication checker cannot see through
-    # pallas_call's opaque outputs — the body is elementwise over the
-    # sharded group axis by construction (each group solved locally).
-    fn = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P("groups"), P("groups")),
-        out_specs=P("groups"),
-        check_vma=False,
-    )
-    return fn(clusters, apps)
-
-
-def grouped_fifo_pack_auto(
-    mesh: Mesh,
-    clusters: ClusterTensors,  # leaves stacked [G, N, ...]
-    apps: AppBatch,  # leaves stacked [G, B, ...]
-    *,
-    fill: str = "tightly-pack",
-    emax: int,
-    num_zones: int,
-) -> BatchedPacking:
-    """`grouped_fifo_pack` with Pallas fast paths: when the subproblems are
-    plain queue-mode and the backend compiles Mosaic, a single-chip mesh
-    solves each group with the Pallas queue kernel back to back (G
-    sequential sub-ms kernels beat one vmapped XLA scan, whose per-step
-    overhead multiplies under vmap), and a multi-chip mesh sharded ONLY on
-    "groups" runs the same kernel per device under shard_map
-    (_grouped_pallas_sharded) — decisions identical, groups are
-    independent. Node-sharded meshes and masked/segmented batches keep the
-    GSPMD vmapped scan."""
-    from spark_scheduler_tpu.ops.pallas_fifo import (
-        PALLAS_MAX_NODES,
-        pallas_available,
-        pallas_eligible,
-    )
-
-    fits = clusters.available.shape[1] <= PALLAS_MAX_NODES
-
-    if (
-        mesh.devices.size > 1
-        and mesh.shape["groups"] == mesh.devices.size
-        and mesh.shape.get("nodes", 1) == 1
-        and clusters.available.shape[0] % mesh.devices.size == 0
-        and fits
-        and pallas_eligible(apps, fill)
-        and pallas_available()
-    ):
-        return _grouped_pallas_sharded(
-            mesh, clusters, apps, fill=fill, emax=emax, num_zones=num_zones
-        )
-    if (
-        mesh.devices.size == 1
-        and fits
-        and pallas_eligible(apps, fill)
-        and pallas_available()
-    ):
-        # Pin execution (and result placement) to the mesh's device.
-        # jax.default_device only steers UNcommitted arrays — jit follows
-        # committed inputs — so committed-elsewhere leaves are moved
-        # explicitly.
-        dev = list(mesh.devices.flat)[0]
-
-        def _pin(x):
-            if x is None:
-                return None
-            if getattr(x, "devices", None) and x.devices() != {dev}:
-                return jax.device_put(x, dev)
-            return x
-
-        clusters = jax.tree_util.tree_map(_pin, clusters)
-        apps = AppBatch(*[_pin(col) for col in apps])
-        with jax.default_device(dev):
-            return _grouped_pallas(
-                clusters,
-                apps,
-                fill=fill,
-                emax=emax,
-                num_zones=num_zones,
-                g=clusters.available.shape[0],
-            )
-    return grouped_fifo_pack(
-        mesh, clusters, apps, fill=fill, emax=emax, num_zones=num_zones
-    )
-
-
-@partial(
-    jax.jit, static_argnames=("fill", "emax", "num_zones", "g", "interpret")
-)
-def _grouped_pallas(
-    clusters, apps, *, fill, emax, num_zones, g, interpret=False
-):
-    """All G group solves in ONE jitted program (one dispatch; G Mosaic
-    kernel launches back to back). Slicing the group axis eagerly would
-    cost a device dispatch per op. `interpret` lets the CPU
-    suite drive the slicing/stacking logic through the Pallas
-    interpreter."""
-    from spark_scheduler_tpu.ops.pallas_fifo import fifo_pack_pallas
-
-    outs = []
-    for i in range(g):
-        c_i = jax.tree_util.tree_map(lambda x: x[i], clusters)
-        a_i = AppBatch(*[None if col is None else col[i] for col in apps])
-        outs.append(
-            fifo_pack_pallas(
-                c_i, a_i, fill=fill, emax=emax, num_zones=num_zones,
-                interpret=interpret,
-            )
-        )
-    return BatchedPacking(
-        *[
-            jnp.stack([getattr(o, f) for o in outs])
-            for f in BatchedPacking._fields
-        ]
-    )
-
-
 def grouped_fifo_pack(
     mesh: Mesh,
     clusters: ClusterTensors,  # leaves stacked [G, N, ...]

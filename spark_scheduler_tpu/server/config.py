@@ -218,8 +218,7 @@ class InstallConfig:
     # FIFO queue position, padding bucket, compile-cache hit, phase wall
     # times) to a bounded ring queryable at GET /debug/decisions, and the
     # solver publishes foundry.spark.scheduler.solver.* telemetry. On by
-    # default — bench.py's recorder-overhead section keeps the hot-path
-    # cost measured; False strips both for the control measurement.
+    # default; False strips both.
     flight_recorder: bool = True
     flight_recorder_capacity: int = 2048
     # Durable decision trace (spark_scheduler_tpu/replay/, ISSUE 17): when
@@ -397,7 +396,7 @@ class InstallConfig:
     @staticmethod
     def enable_jax_compile_cache(cache_dir: Optional[str] = None) -> str:
         """THE persistent compilation cache setup, shared by the server
-        bootstrap, bench.py, chip_smoke.py and hack/ha_shard_bench.py.
+        bootstrap, chip_smoke.py and hack/ha_shard_bench.py.
 
         The directory is, in order: `cache_dir` when given (the install
         key `jax-compilation-cache-dir`); else JAX_COMPILATION_CACHE_DIR,

@@ -8,8 +8,8 @@ live here — byte-compatible with the pre-split server (the raw-socket HTTP
 tests pin every edge).
 
 This transport remains the DEFAULT (`server.transport: threaded`) until a
-benched A/B proves the async event loop's ceiling on the target box
-(bench.py `transport_rig_ceiling`); its thread-per-connection model is also
+benched A/B proves the async event loop's ceiling on the target box; its
+thread-per-connection model is also
 the simplest one to reason about under debuggers and profilers.
 
 Ingest lanes: this transport keeps its stdlib socket framing on BOTH

@@ -225,9 +225,8 @@ class Harness:
 
 def overcommit_violations(app, backend) -> list[tuple[str, str]]:
     """[(node_name, dimension)] wherever hard+soft reservations + overhead
-    exceed allocatable — THE over-commit invariant, shared by bench.py's
-    10k serving bench and tests/test_invariant_soak.py so the definition
-    cannot drift. A reservation on a node the backend no longer knows is
+    exceed allocatable — THE over-commit invariant of
+    tests/test_invariant_soak.py and the soaks. A reservation on a node the backend no longer knows is
     reported as ("<name>", "missing-node")."""
     from spark_scheduler_tpu.models.resources import Resources
 

@@ -15,9 +15,8 @@ loop shape), asserting global scheduling invariants as it goes:
   4. Idempotent retries never double-book: resubmitting an admitted driver
      returns its reserved node and changes no reservation.
 
-Lives in the package (not tests/) so both the CPU test matrix
-(tests/test_invariant_soak.py) and the ON-SILICON soak the bench runs
-(bench.py bench_tpu_soak — Pallas window path under churn) drive one
+Lives in the package (not tests/) so the CPU test matrix
+(tests/test_invariant_soak.py) and soaks run on the chip drive one
 engine. Anchor: extendertest harness pattern
 (/root/reference/internal/extender/extendertest/extender_test_utils.go:51-397).
 """
@@ -814,8 +813,7 @@ class HAChaosSoak:
         (promotion + retry, the TTL itself is crossed on the virtual
         clock).
 
-    Driven fast by tests/test_ha_chaos_soak.py and on real clusters by
-    bench.py's ha_failover section.
+    Driven fast by tests/test_ha_chaos_soak.py.
 
     The kill itself rides the unified FaultInjector (ISSUE 9): the
     `replica.kill` surface is fired once per cycle and the PLAN decides

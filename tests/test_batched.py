@@ -532,34 +532,3 @@ def test_grouped_2d_parallel_matches_per_group():
         np.testing.assert_array_equal(
             np.asarray(got.admitted[gi]), np.asarray(want.admitted)
         )
-
-
-def test_grouped_pallas_sharded_matches_per_group():
-    """The multi-chip Mosaic path (VERDICT r3 #5): groups sharded across
-    the full 8-device mesh with the Pallas queue kernel running per device
-    under shard_map (interpret mode on the CPU mesh) must equal the
-    unsharded XLA scan group-for-group."""
-    from spark_scheduler_tpu.parallel.solve import _grouped_pallas_sharded
-
-    rng = np.random.default_rng(17)
-    n_dev = 8
-    clusters = [random_cluster(rng, 24) for _ in range(2 * n_dev)]
-    batches = [random_apps(rng, 4, pad_to=4) for _ in range(2 * n_dev)]
-    mesh = make_solver_mesh(n_groups=n_dev, n_nodes_shards=1)
-    stacked_c, stacked_a = stack_groups(clusters, batches)
-    got = _grouped_pallas_sharded(
-        mesh, stacked_c, stacked_a, fill="tightly-pack", emax=EMAX,
-        num_zones=NUM_ZONES, interpret=True,
-    )
-    for gi in range(2 * n_dev):
-        want = batched_fifo_pack(
-            clusters[gi], batches[gi], fill="tightly-pack", emax=EMAX,
-            num_zones=NUM_ZONES,
-        )
-        for field in ("driver_node", "executor_nodes", "admitted", "packed",
-                      "available_after"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(got, field)[gi]),
-                np.asarray(getattr(want, field)),
-                err_msg=f"group {gi} {field}",
-            )

@@ -1,8 +1,7 @@
 """HA chaos soak (ISSUE 8 acceptance): kill the leader mid-burst, assert
 zero double-placements, zero reservation-invariant violations, and a
-bounded placement-latency spike — the engine lives in testing/soak.py so
-this fast CI leg and bench.py's ha_failover section drive one
-implementation. `HA_CHAOS_CYCLES` scales it up for the soak CI job."""
+bounded placement-latency spike — the engine lives in testing/soak.py.
+`HA_CHAOS_CYCLES` scales it up for the soak CI job."""
 
 from __future__ import annotations
 

@@ -115,8 +115,8 @@ def test_usage_cache_soft_reporters():
 
 
 def test_histogram_stats_p99_and_min():
-    """Histogram.stats() exposes min/p99 alongside p50/p95 — bench.py and
-    the autoscaler report p99, so the registry view must carry it too."""
+    """Histogram.stats() exposes min/p99 alongside p50/p95 — the
+    autoscaler reports p99, so the registry view must carry it too."""
     from spark_scheduler_tpu.metrics.registry import Histogram
 
     h = Histogram()

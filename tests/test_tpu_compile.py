@@ -1,9 +1,10 @@
-"""The Mosaic kernels compiled by the TPU's compiler for a described v5e.
+"""The Mosaic window kernel compiled by the TPU's compiler for a described
+v5e.
 
-Nothing runs: each case lowers and compiles one kernel for a chip that is
+Nothing runs: each case lowers and compiles the kernel for a chip that is
 described, not attached, and checks that the Mosaic custom call is in the
 program. 4,096 nodes is the smallest node count on the 8-sublane layout
-(ops/pallas_fifo.py `_SUBLANE_FOLD_MIN_NODES`). The served 10k and 100k
+(ops/pallas_window.py `_SUBLANE_FOLD_MIN_NODES`). The served 10k and 100k
 node buckets compile in ~30 s and ~140 s, too slow for this suite;
 chip_smoke.py runs them on the chip.
 """
@@ -18,8 +19,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from spark_scheduler_tpu.models.cluster import ClusterTensors
-from spark_scheduler_tpu.ops.batched import make_app_batch
-from spark_scheduler_tpu.ops.pallas_fifo import fifo_pack_pallas
 from spark_scheduler_tpu.ops.pallas_window import (
     segmented_window_from_flat,
     window_pack_pallas,
@@ -77,28 +76,22 @@ def _assert_mosaic(lowered):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fill", STRATEGIES)
-def test_queue_kernel_compiles_for_v5e(one_chip, fill):
-    apps = make_app_batch(
-        np.zeros((16, 3)), np.zeros((16, 3)), np.zeros(16),
-        skippable=np.zeros(16, bool),
-    )
-    lowered = fifo_pack_pallas.lower(
-        _shapes(_cluster(N_NODES), one_chip), _shapes(apps, one_chip),
-        fill=fill, emax=EMAX, num_zones=NUM_ZONES,
-    )
-    _assert_mosaic(lowered)
+# Served window buckets (core/solver.py _build_segmented_window), as
+# (segments, rows): `window`, the smallest; `queue`, a FIFO backlog's one
+# request with up to 256 rows in its segment, the largest row bucket the
+# benchmark's backlogs reach.
+SHAPES = {"window": (4, 16), "queue": (4, 256)}
 
 
 @pytest.mark.parametrize("fill", STRATEGIES)
-def test_window_kernel_compiles_for_v5e(one_chip, fill):
-    # The smallest served window bucket (core/solver.py
-    # _build_segmented_window): 4 segments of 16 rows.
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_window_kernel_compiles_for_v5e(one_chip, shape, fill):
+    pad_segments, pad_rows = SHAPES[shape]
     win, _, _ = segmented_window_from_flat(
         np.zeros((1, 3), np.int32), np.zeros((1, 3), np.int32),
         np.zeros(1, np.int32), np.zeros(1, bool), [1],
         [np.zeros(N_NODES, bool)], [np.zeros(N_NODES, bool)],
-        pad_segments=4, pad_rows=16,
+        pad_segments=pad_segments, pad_rows=pad_rows,
     )
     lowered = window_pack_pallas.lower(
         _shapes(_cluster(N_NODES), one_chip), _shapes(win, one_chip),
